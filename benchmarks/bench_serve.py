@@ -10,8 +10,8 @@ Measures the two serving-performance levers this repo ships:
   agg     processor scatter-add implementations inside the jitted
           points->prediction pipeline: 'xla' (plain segment_sum), 'sorted'
           (device argsort once per graph + indices_are_sorted reduce),
-          'pallas' (sorted block packing + one-hot-MXU kernel; interpret
-          mode off-TPU, so its absolute time here is NOT TPU performance).
+          'pallas' (sorted block packing + one-hot-MXU kernel; the Pallas
+          interpreter on the CPU, so its time there is NOT TPU performance).
           Output parity vs 'xla' is recorded alongside the timings.
   autoscale
           nonstationary request-size traffic (small-resolution phase, then
@@ -33,12 +33,15 @@ Measures the two serving-performance levers this repo ships:
           process-restart latency (``time_to_first_result_s`` = server
           construction/restore + first served request, measured in a fresh
           subprocess after imports) three ways: a truly fresh server
-          (compiles everything), a fresh server with a WARM persistent
-          compilation cache (re-traces, loads executables from disk), and
-          a server restored from a deploy artifact
+          (compile cache off, compiles everything), a fresh server with a
+          WARM persistent compilation cache (re-traces, loads executables
+          from disk), and a server restored from a deploy artifact
           (``GNNServer.from_artifact``: zero compiles, zero
           recalibration). Asserts the artifact restore is >= 3x faster
-          than the fresh cold start and compiles nothing.
+          than the fresh cold start and compiles nothing. The deployment
+          is built in a child too and the children run one at a time:
+          only one process can hold an accelerator, so this process stays
+          off JAX until they are done.
 
 Requests use a densely tessellated geometry (``--nu/--nv``; default ~260k
 triangles, the realistic STL regime) so host surface sampling is a real
@@ -68,6 +71,7 @@ import numpy as np
 
 from common import emit
 
+from repro.ckpt import compile_cache
 from repro.configs.base import GNNConfig
 from repro.data import geometry as geo
 from repro.launch.serve_gnn import GNNServer
@@ -354,25 +358,32 @@ def bench_overload(cfg, reference, max_batch, smoke, rows, report):
 def _coldstart_child(args):
     """Measure time-to-first-result in THIS fresh process (post-import).
 
-    Modes: ``fresh`` builds a server from scratch (optionally against a
-    persistent compile-cache dir), ``artifact`` restores
-    ``GNNServer.from_artifact``. Emits one ``COLDSTART_JSON {...}`` line
-    the parent parses.
+    Modes: ``build`` serves once with the persistent compile cache on and
+    saves the deploy artifact (the deployment the restarts start from),
+    ``fresh`` builds a server from scratch with the cache off, ``warmcache``
+    builds one against the cache ``build`` filled, and ``artifact``
+    restores ``GNNServer.from_artifact``. Emits one ``COLDSTART_JSON
+    {...}`` line the parent parses.
     """
     verts, faces = geo.car_surface(geo.sample_params(0), nu=args.nu,
                                    nv=args.nv)
     bucket = args.bucket
+    mode = args.coldstart_child
+    if mode == "fresh":
+        jax.config.update("jax_enable_compilation_cache", False)
+    else:
+        compile_cache.enable(args.compile_cache)
     t0 = time.perf_counter()
-    if args.coldstart_child == "artifact":
+    if mode == "artifact":
         server = GNNServer.from_artifact(args.artifact_path)
     else:
-        cfg = GNNConfig().reduced()
-        if args.compile_cache:
-            cfg = cfg.replace(compile_cache_dir=args.compile_cache)
-        server = GNNServer(cfg, (bucket,), max_batch=args.max_batch,
+        server = GNNServer(GNNConfig().reduced(), (bucket,),
+                           max_batch=args.max_batch,
                            reference=(verts, faces), check_requests=False)
     [res] = server.serve([(verts, faces, bucket)])
     t_first = time.perf_counter() - t0
+    if mode == "build":
+        server.save_artifact(args.artifact_path)
     assert res.error is None and np.isfinite(res.fields).all()
     warm = []
     for _ in range(3):
@@ -381,7 +392,9 @@ def _coldstart_child(args):
         warm.append(time.perf_counter() - t1)
     rep = server.stats.report()
     print("COLDSTART_JSON " + json.dumps({
-        "mode": args.coldstart_child,
+        "mode": mode,
+        "backend": jax.default_backend(),
+        "compile_cache_dir": compile_cache.enabled_dir(),
         "time_to_first_result_s": t_first,
         "warm_p50_s": float(np.median(warm)),
         "bucket_compiles": rep["bucket_compiles"],
@@ -390,38 +403,27 @@ def _coldstart_child(args):
     }))
 
 
-def bench_coldstart(cfg, bucket, max_batch, nu, nv, compile_cache_dir, rows,
+def bench_coldstart(bucket, max_batch, nu, nv, compile_cache_dir, rows,
                     report):
     """Restart latency: fresh vs warm-compile-cache vs deploy artifact.
 
-    The parent builds the deployment (one server, one served request,
-    persistent cache populated, artifact saved), then each restart flavor
-    runs in its own subprocess so jit caches, tracing and backend state
-    are genuinely cold. ``time_to_first_result_s`` is construction/restore
-    + first request, excluding interpreter/import startup (identical
-    across flavors).
+    A ``build`` child makes the deployment (one served request, persistent
+    cache populated, artifact saved); then each restart flavor runs in a
+    child of its own, one after another, so jit caches, tracing and
+    backend state are genuinely cold. This process never touches JAX.
+    ``time_to_first_result_s`` is construction/restore + first request,
+    excluding interpreter/import startup (identical across flavors).
     """
     tmp = tempfile.mkdtemp(prefix="bench-coldstart-")
-    cache = compile_cache_dir or os.path.join(tmp, "xla-cache")
     art = os.path.join(tmp, "deploy.msgpack")
-    verts, faces = geo.car_surface(geo.sample_params(0), nu=nu, nv=nv)
 
-    pcfg = cfg.replace(compile_cache_dir=cache)
-    t0 = time.perf_counter()
-    server = GNNServer(pcfg, (bucket,), max_batch=max_batch,
-                       reference=(verts, faces), check_requests=False)
-    server.serve([(verts, faces, bucket)])
-    parent_first_s = time.perf_counter() - t0
-    prep = server.stats.report()
-    server.save_artifact(art)
-
-    def child(mode, cache_dir=None):
+    def child(mode):
         cmd = [sys.executable, os.path.abspath(__file__),
                "--coldstart-child", mode, "--bucket", str(bucket),
                "--max-batch", str(max_batch), "--nu", str(nu),
                "--nv", str(nv), "--artifact-path", art]
-        if cache_dir:
-            cmd += ["--compile-cache", cache_dir]
+        if compile_cache_dir:
+            cmd += ["--compile-cache", compile_cache_dir]
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=900)
         assert proc.returncode == 0, \
@@ -430,8 +432,9 @@ def bench_coldstart(cfg, bucket, max_batch, nu, nv, compile_cache_dir, rows,
                 if ln.startswith("COLDSTART_JSON ")][-1]
         return json.loads(line.split(" ", 1)[1])
 
-    fresh = child("fresh", cache_dir=os.path.join(tmp, "empty-cache"))
-    warmcache = child("fresh", cache_dir=cache)
+    build = child("build")
+    fresh = child("fresh")
+    warmcache = child("warmcache")
     artifact = child("artifact")
 
     # contract: a compile-cache restart compiles nothing (disk loads); an
@@ -449,13 +452,11 @@ def bench_coldstart(cfg, bucket, max_batch, nu, nv, compile_cache_dir, rows,
         f"{artifact['time_to_first_result_s']:.2f}s)")
 
     report["coldstart"] = {
-        "parent": {"time_to_first_result_s": parent_first_s,
-                   "bucket_compiles": prep["bucket_compiles"],
-                   "cache_loads": prep["cache_loads"]},
-        "fresh": fresh, "warm_compile_cache": warmcache,
+        "build": build, "fresh": fresh, "warm_compile_cache": warmcache,
         "artifact": artifact,
         "artifact_speedup_vs_fresh": speedup,
-        "compile_cache_dir": cache, "artifact_path": art,
+        "compile_cache_dir": build["compile_cache_dir"],
+        "artifact_path": art,
     }
     for name, r in (("fresh", fresh), ("warmcache", warmcache),
                     ("artifact", artifact)):
@@ -485,7 +486,9 @@ def main():
                     help="skip the interpret-mode pallas aggregation run")
     ap.add_argument("--compile-cache", default=None,
                     help="persistent XLA compile-cache dir for the "
-                         "coldstart scenario (default: a fresh tmpdir)")
+                         "coldstart scenario (ignored when "
+                         "$JAX_COMPILATION_CACHE_DIR is set; default "
+                         "<checkout>/.jax_cache)")
     ap.add_argument("--only", default=None,
                     help="comma-separated scenario subset to run "
                          "(flush,agg,autoscale,sharded_autoscale,coldstart,"
@@ -494,7 +497,7 @@ def main():
                     help="shard count for the sharded_autoscale scenario "
                          "(forced host devices in a subprocess)")
     ap.add_argument("--coldstart-child", default=None,
-                    choices=("fresh", "artifact"),
+                    choices=("build", "fresh", "warmcache", "artifact"),
                     help="internal: run as a coldstart measurement child")
     ap.add_argument("--sharded-child", action="store_true",
                     help="internal: run as the sharded autoscale child")
@@ -531,8 +534,7 @@ def main():
         "config": {
             "bucket": bucket, "max_batch": args.max_batch,
             "requests": n_req, "nu": nu, "nv": nv, "n_faces": n_faces,
-            "reduced": True, "backend": jax.default_backend(),
-            "smoke": bool(args.smoke),
+            "reduced": True, "smoke": bool(args.smoke),
         },
     }
     all_scenarios = ("flush", "agg", "autoscale", "sharded_autoscale",
@@ -540,6 +542,14 @@ def main():
     scenarios = set((args.only or ",".join(all_scenarios)).split(","))
     unknown = scenarios - set(all_scenarios)
     assert not unknown, f"unknown --only scenarios: {sorted(unknown)}"
+    # child-process scenarios first, while this process is still off JAX
+    # (a process that has touched JAX holds the accelerator)
+    if "sharded_autoscale" in scenarios:
+        bench_sharded_autoscale(args.max_batch, nu, nv, args.shard_devices,
+                                args.smoke, rows, report)
+    if "coldstart" in scenarios:
+        bench_coldstart(bucket, args.max_batch, nu, nv, args.compile_cache,
+                        rows, report)
     if "flush" in scenarios:
         bench_flush_modes(cfg, reqs, bucket, args.max_batch, reference, reps,
                           rows, report)
@@ -549,12 +559,6 @@ def main():
     if "autoscale" in scenarios:
         bench_autoscale(cfg, reference, args.max_batch, args.smoke, rows,
                         report)
-    if "sharded_autoscale" in scenarios:
-        bench_sharded_autoscale(args.max_batch, nu, nv, args.shard_devices,
-                                args.smoke, rows, report)
-    if "coldstart" in scenarios:
-        bench_coldstart(cfg, bucket, args.max_batch, nu, nv,
-                        args.compile_cache, rows, report)
     if "overload" in scenarios:
         bench_overload(cfg, reference, args.max_batch, args.smoke, rows,
                        report)
@@ -567,6 +571,7 @@ def main():
                 assert {"count", "mean_ms", "p50_ms", "p95_ms",
                         "total_s"} <= set(s), (key, st, s)
             assert any(s["count"] > 0 for s in stages.values()), stages
+    report["config"]["backend"] = jax.default_backend()
     emit(rows)
     if args.json:
         with open(args.json, "w") as f:

@@ -14,12 +14,12 @@ bundling a trained server's *learned and compiled* state:
   backend supports it, so the restored server's first request runs a
   deserialized program: zero traces, zero XLA compiles.
 
-Executable serialization is backend-dependent (some backends cannot re-link
-a deserialized program), so :func:`serialize_compiled` self-checks every
-payload by deserializing it immediately; a payload that fails the check is
-dropped at save time and the restored server falls back to the persistent
-compilation cache (``repro.ckpt.compile_cache``) — a re-trace plus a disk
-load, still never a full compile.
+Executable serialization is backend-dependent (XLA:CPU in JAX 0.9.0 cannot
+serialize executables at all; the TPU can), so :func:`serialize_compiled`
+self-checks every payload by deserializing it immediately; a payload that
+fails is dropped at save time and the restored server falls back to the
+persistent compilation cache (``repro.ckpt.compile_cache``) — a re-trace
+plus a disk load, still never a full compile.
 """
 from __future__ import annotations
 
@@ -90,9 +90,8 @@ def serialize_compiled(compiled) -> Optional[bytes]:
     """Serialize an AOT-compiled executable; ``None`` if unsupported.
 
     The payload is self-checked by deserializing it in-process: a backend
-    that serializes happily but cannot re-link the program (seen on some
-    CPU fusions) is caught HERE, at deploy time, rather than at restore
-    time in production.
+    that serializes but cannot re-link the program is caught HERE, at
+    deploy time, rather than at restore time in production.
     """
     try:
         from jax.experimental.serialize_executable import (

@@ -7,6 +7,12 @@ version). A restarted server or trainer re-traces its programs but the
 expensive backend compile becomes a millisecond disk load — the difference
 between the ~0.5–2 s per-bucket compile tax and warm-path restart latency.
 
+Where the cache lives (:func:`enable`): ``$JAX_COMPILATION_CACHE_DIR`` when
+it is set (JAX reads it itself and no other directory is set in code),
+else the directory the caller names, else the fixed ``.jax_cache/`` at the
+root of the checkout. The path is part of every entry's key, so it never
+depends on a temporary directory, a process id or the time.
+
 Attribution: :class:`CompileEvents` snapshots JAX's monitoring counters for
 persistent-cache hits (``/jax/compilation_cache/cache_hits`` — a disk load)
 vs misses (``cache_misses`` — a true XLA compile). The serving stats use
@@ -18,8 +24,8 @@ milliseconds and must not be reported as a compile.
 from __future__ import annotations
 
 import logging
+import os
 import threading
-from contextlib import contextmanager
 from typing import Optional, Tuple
 
 import jax
@@ -48,77 +54,57 @@ def _on_event(event: str, **kw) -> None:
 
 def _install_listener() -> None:
     global _listener_installed
-    if _listener_installed:
-        return
-    try:
+    if not _listener_installed:
         jax.monitoring.register_event_listener(_on_event)
         _listener_installed = True
-    except Exception as e:                      # pragma: no cover
-        log.warning("jax.monitoring unavailable (%s): persistent-cache "
-                    "loads will be reported as compiles", e)
 
 
-def enable(cache_dir: Optional[str]) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir``.
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    Idempotent; a falsy ``cache_dir`` is a no-op (returns whether the cache
-    is enabled). Re-enabling with a DIFFERENT directory logs a warning and
-    switches — the cache is process-global JAX config, so the last caller
-    wins. The entry-size/compile-time floors are dropped so every program
-    is cached (the default floors skip small fast compiles, which is
-    exactly the wrong policy for a bucket ladder of mid-size programs).
+
+def enable(cache_dir: Optional[str] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Lookup order: ``$JAX_COMPILATION_CACHE_DIR``, then ``cache_dir``, then
+    :data:`DEFAULT_DIR`. Idempotent; switching to a different directory
+    logs a warning — the cache is process-global JAX config, so the last
+    caller wins. The entry-size/compile-time floors are dropped so every
+    program is cached (the default floors skip small fast compiles, which
+    is exactly the wrong policy for a bucket ladder of mid-size programs).
     """
     global _enabled_dir
-    if not cache_dir:
-        return _enabled_dir is not None
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    target = env_dir or cache_dir or DEFAULT_DIR
     with _lock:
         already = _enabled_dir
-    if already == cache_dir:
-        return True
+    if already == target:
+        return target
     if already is not None:
         log.warning("compile cache moving from %s to %s (process-global "
-                    "JAX config: last caller wins)", already, cache_dir)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+                    "JAX config: last caller wins)", already, target)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", target)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     # jax initializes its cache object once, at the first compile that
     # probes it — any compile before enable() (imports, PRNG setup) would
     # freeze the cache as disabled for the whole process. Reset so the
     # next probe re-initializes against the directory just configured.
-    try:
-        from jax._src.compilation_cache import reset_cache
-        reset_cache()
-    except Exception as e:                      # pragma: no cover
-        log.warning("could not reset jax's compilation cache (%s); the "
-                    "new cache dir applies only if no compile ran yet", e)
+    from jax._src.compilation_cache import reset_cache
+    reset_cache()
     _install_listener()
     with _lock:
-        _enabled_dir = cache_dir
-    log.info("persistent XLA compilation cache enabled at %s", cache_dir)
-    return True
+        _enabled_dir = target
+    log.info("persistent XLA compilation cache enabled at %s", target)
+    return target
 
 
 def enabled_dir() -> Optional[str]:
     """The active cache directory, or None when the cache is off."""
     with _lock:
         return _enabled_dir
-
-
-@contextmanager
-def suspended():
-    """Temporarily bypass the persistent cache (process-global config).
-
-    AOT executable serialization needs a freshly-compiled program: an
-    executable LOADED from the persistent cache serializes a payload whose
-    re-link fails ("Symbols not found" on CPU), so deploy-artifact builds
-    compile under this context to guarantee a serializable executable.
-    """
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        yield
-    finally:
-        jax.config.update("jax_enable_compilation_cache", prev)
 
 
 class CompileEvents:

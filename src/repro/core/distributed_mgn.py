@@ -25,10 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import GNNConfig
 from repro.core import halo as halo_lib
@@ -200,8 +196,8 @@ def make_dmgn_grad_fn(mesh, cfg: GNNConfig, denom: float, axis: str = "data"):
                   ("node_feats", "targets", "node_mask", "edge_feats",
                    "edge_mask", "senders_slot", "receivers",
                    "boundary_gather", "boundary_mask")}
-    fn = shard_map(local, mesh=mesh, in_specs=(P(), shard_spec),
-                   out_specs=(P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), shard_spec),
+                       out_specs=(P(), P()))
     return jax.jit(fn)
 
 

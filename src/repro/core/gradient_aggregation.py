@@ -30,17 +30,11 @@ def tree_pvary(tree, axes: tuple):
     Applied to replicated params *before* ``value_and_grad`` so that JAX's
     transpose does NOT auto-insert a per-call psum — we aggregate gradients
     ourselves with exactly one psum per step (the paper's scheme)."""
+    axes = tuple(axes)
+
     def _v(x):
-        try:
-            return jax.lax.pcast(x, tuple(axes), to="varying")
-        except (AttributeError, TypeError):
-            pass
-        except ValueError:
-            return x  # already varying over these axes
-        pvary = getattr(jax.lax, "pvary", None)
-        if pvary is not None:
-            return pvary(x, tuple(axes))
-        return x  # legacy shard_map: replication handled by check_rep
+        missing = tuple(a for a in axes if a not in jax.typeof(x).vma)
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
     return jax.tree_util.tree_map(_v, tree)
 
 
@@ -126,10 +120,6 @@ def shard_map_aggregate_gradients(mesh, grad_fn: Callable,
     of the ``axes`` sizes. Equivalence to the single-device scan (and to
     full-graph gradients) is pinned by ``tests/test_train_equivalence.py``.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     axes = tuple(axes)
 
@@ -142,6 +132,6 @@ def shard_map_aggregate_gradients(mesh, grad_fn: Callable,
         with jax.named_scope("train/psum"):
             return jax.lax.psum(loss, axes), jax.lax.psum(grads, axes)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
-                   out_specs=(P(), P()))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
+                       out_specs=(P(), P()))
     return jax.jit(fn) if jit else fn

@@ -298,8 +298,7 @@ def candidate_lists(points, n_valid, spec: GridSpec):
     return jnp.maximum(cand, 0), cand_valid, valid
 
 
-def knn(points, n_valid, spec: GridSpec, *, impl: str = "xla",
-        interpret: bool = True):
+def knn(points, n_valid, spec: GridSpec, *, impl: str = "xla"):
     """Fixed-degree kNN: (N, 3) points -> ((N, k) idx, (N, k) d2, (N, k) mask).
 
     ``n_valid`` is a (traced) scalar: points[n_valid:] are padding and are
@@ -311,7 +310,7 @@ def knn(points, n_valid, spec: GridSpec, *, impl: str = "xla",
     cand_pos = points.astype(jnp.float32)[cand_idx]
     idx, d2, mask = knn_ops.topk_neighbors(
         points.astype(jnp.float32), cand_pos, cand_idx, cand_valid,
-        spec.k, impl=impl, interpret=interpret)
+        spec.k, impl=impl)
     mask = mask & valid[:, None]
     idx = jnp.where(mask, idx, -1)
     return idx, d2, mask

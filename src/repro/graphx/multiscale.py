@@ -51,7 +51,7 @@ def auto_multiscale_spec(level_sizes: Sequence[int], k: int = 6,
 
 
 def multiscale_edges(points, n_valid, ms: MultiscaleSpec, *,
-                     impl: str = "xla", interpret: bool = True):
+                     impl: str = "xla"):
     """Union of per-level symmetric kNN edges with cross-level dedup masks.
 
     points: (n_finest, 3); n_valid: traced scalar — valid points must be a
@@ -77,7 +77,7 @@ def multiscale_edges(points, n_valid, ms: MultiscaleSpec, *,
         nv = (jnp.minimum(n_valid, n_l) if n_valid.ndim == 0
               else n_valid[lvl])
         idx, _, mask = hashgrid.knn(points[:n_l], nv, gspec,
-                                    impl=impl, interpret=interpret)
+                                    impl=impl)
         nbrs.append((idx, mask))
 
     seg_s, seg_r, seg_m = [], [], []

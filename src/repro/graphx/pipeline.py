@@ -52,8 +52,7 @@ def make_featurizer(cfg: GNNConfig, *,
 
 
 def make_step_fn(cfg: GNNConfig, *,
-                 norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None,
-                 interpret: bool = True):
+                 norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None):
     """One physics step over a featurized graph: ``step(params, graph,
     state)`` -> next state (N, node_out).
 
@@ -73,15 +72,14 @@ def make_step_fn(cfg: GNNConfig, *,
                 params, cfg, nf, graph["edge_feats"],
                 graph["senders"], graph["receivers"], state,
                 edge_mask=graph["emask"].astype(nf.dtype),
-                out_stats=out_stats, interpret=interpret)
+                out_stats=out_stats)
 
     return step
 
 
 def make_graph_forward(cfg: GNNConfig, *,
                        norm_in: Optional[Tuple[np.ndarray, np.ndarray]] = None,
-                       norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None,
-                       interpret: bool = True):
+                       norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None):
     """Featurize + model forward over an already-built edge set.
 
     Returns ``forward(params, points, normals, senders, receivers, emask)``
@@ -100,11 +98,10 @@ def make_graph_forward(cfg: GNNConfig, *,
     scatter-add, receiver-``sorted`` segment reduce, ``pallas`` one-hot-MXU
     kernel) run device-side inside the jitted pipeline —
     ``segment_agg.prepare_device`` made the sort/packing jittable, so none
-    of them needs host preprocessing. ``interpret`` applies to the Pallas
-    path only (True on CPU, False on real TPUs).
+    of them needs host preprocessing.
     """
     featurize = make_featurizer(cfg, norm_in=norm_in)
-    step = make_step_fn(cfg, norm_out=norm_out, interpret=interpret)
+    step = make_step_fn(cfg, norm_out=norm_out)
 
     def forward(params, points, normals, senders, receivers, emask):
         graph = featurize(points, normals, senders, receivers, emask)
@@ -116,36 +113,36 @@ def make_graph_forward(cfg: GNNConfig, *,
 
 
 @functools.lru_cache(maxsize=64)
-def _cached_edges_fn(ms: MultiscaleSpec, knn_impl: str, interpret: bool):
+def _cached_edges_fn(ms: MultiscaleSpec, knn_impl: str):
     def edges(points, n_valid):
         return multiscale_edges(points.astype(jnp.float32), n_valid, ms,
-                                impl=knn_impl, interpret=interpret)
+                                impl=knn_impl)
     return jax.jit(edges)
 
 
 def make_edges_fn(ms: MultiscaleSpec, *, knn_impl: str = "xla",
-                  interpret: bool = True, jit: bool = True):
+                  jit: bool = True):
     """Graph construction alone: ``edges(points, n_valid) -> (senders,
     receivers, emask)`` with the fixed-shape layout of ``multiscale_edges``.
 
     The construction half of :func:`make_infer_fn`, for callers that need
     the edge list itself rather than a prediction — e.g. the mesh-free
     training data path, which builds edges on device and partitions them on
-    host. The jitted variant is memoized per (spec, impl, interpret), so
+    host. The jitted variant is memoized per (spec, impl), so
     repeated calls with the same grids — clouds calibrated to identical
     resolutions — reuse one compiled program instead of re-tracing.
     """
     if jit:
-        return _cached_edges_fn(ms, knn_impl, interpret)
+        return _cached_edges_fn(ms, knn_impl)
 
     def edges(points, n_valid):
         return multiscale_edges(points.astype(jnp.float32), n_valid, ms,
-                                impl=knn_impl, interpret=interpret)
+                                impl=knn_impl)
     return edges
 
 
 def device_multiscale_edges(points: np.ndarray, level_sizes, k: int, *,
-                            knn_impl: str = "xla", interpret: bool = True):
+                            knn_impl: str = "xla"):
     """One-shot device edge build for a host-resident nested cloud.
 
     Calibrates per-level grids on THIS cloud (so the hash-grid kNN matches
@@ -165,7 +162,7 @@ def device_multiscale_edges(points: np.ndarray, level_sizes, k: int, *,
     grids = tuple(hashgrid.calibrate_spec(pts[:n], k, n_points=n)
                   for n in levels)
     ms = MultiscaleSpec(level_sizes=levels, k=k, grids=grids)
-    s, r, em = make_edges_fn(ms, knn_impl=knn_impl, interpret=interpret)(
+    s, r, em = make_edges_fn(ms, knn_impl=knn_impl)(
         jnp.asarray(pts), levels[-1])
     em = np.asarray(em)
     return (np.asarray(s)[em].astype(np.int32),
@@ -174,7 +171,7 @@ def device_multiscale_edges(points: np.ndarray, level_sizes, k: int, *,
 
 
 def make_infer_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
-                  knn_impl: str = "xla", interpret: bool = True,
+                  knn_impl: str = "xla",
                   norm_in: Optional[Tuple[np.ndarray, np.ndarray]] = None,
                   norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None,
                   jit: bool = True):
@@ -184,14 +181,13 @@ def make_infer_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
     real points (a prefix). ``norm_in``/``norm_out`` are optional (mean, std)
     pairs folded into the compiled program (input encoding / output decoding).
     """
-    forward = make_graph_forward(cfg, norm_in=norm_in, norm_out=norm_out,
-                                 interpret=interpret)
+    forward = make_graph_forward(cfg, norm_in=norm_in, norm_out=norm_out)
 
     def infer(params, points, normals, n_valid):
         points = points.astype(jnp.float32)
         with jax.named_scope("graphx/knn_edges"):
             senders, receivers, emask = multiscale_edges(
-                points, n_valid, ms, impl=knn_impl, interpret=interpret)
+                points, n_valid, ms, impl=knn_impl)
         return forward(params, points, normals, senders, receivers, emask)
 
     return jax.jit(infer) if jit else infer
@@ -217,7 +213,7 @@ def make_batched_infer_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
 
 
 def make_prefill_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
-                    knn_impl: str = "xla", interpret: bool = True,
+                    knn_impl: str = "xla",
                     norm_in: Optional[Tuple[np.ndarray, np.ndarray]] = None,
                     jit: bool = True):
     """Rollout prefill: ``prefill(points, normals, n_valid)`` -> graph dict.
@@ -233,7 +229,7 @@ def make_prefill_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
         points = points.astype(jnp.float32)
         with jax.named_scope("graphx/knn_edges"):
             senders, receivers, emask = multiscale_edges(
-                points, n_valid, ms, impl=knn_impl, interpret=interpret)
+                points, n_valid, ms, impl=knn_impl)
         return featurize(points, normals, senders, receivers, emask)
 
     return jax.jit(prefill) if jit else prefill
@@ -241,8 +237,7 @@ def make_prefill_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
 
 def make_generate_fn(cfg: GNNConfig, *, steps: int,
                      norm_out: Optional[Tuple[np.ndarray, np.ndarray]] = None,
-                     interpret: bool = True, jit: bool = True,
-                     donate: bool = False):
+                     jit: bool = True, donate: bool = False):
     """Rollout generate: scan ``steps`` physics steps over a slot table.
 
     Returns ``gen(params, graph, state, remaining) -> (state', remaining')``
@@ -258,7 +253,7 @@ def make_generate_fn(cfg: GNNConfig, *, steps: int,
     table in place on accelerators (no-op on CPU, same policy as
     :func:`make_batched_infer_fn`).
     """
-    step = make_step_fn(cfg, norm_out=norm_out, interpret=interpret)
+    step = make_step_fn(cfg, norm_out=norm_out)
 
     def one(params, graph, state, remaining):
         def body(carry, _):

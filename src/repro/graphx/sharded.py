@@ -49,11 +49,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map
-
 from repro.configs.base import GNNConfig
 from repro.core import halo as halo_lib
 from repro.core import partitioning
@@ -481,7 +476,7 @@ def shard_spec_for(bucket_size: int, n_shards: int, halo_hops: int,
 
 def make_sharded_infer_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
                           axis: str = "data", knn_impl: str = "xla",
-                          interpret: bool = True, norm_in=None, norm_out=None,
+                          norm_in=None, norm_out=None,
                           jit: bool = True, pack_width: int = 1):
     """Build ``infer(params, batch) -> (P[, G], Nmax, node_out)`` under
     shard_map.
@@ -503,8 +498,7 @@ def make_sharded_infer_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
     solo on that geometry. The output grows a matching G axis, consumed by
     ``PackPlan.gather``.
     """
-    forward = make_graph_forward(cfg, norm_in=norm_in, norm_out=norm_out,
-                                 interpret=interpret)
+    forward = make_graph_forward(cfg, norm_in=norm_in, norm_out=norm_out)
     ms = sspec.ms
     pack_width = int(pack_width)
 
@@ -512,7 +506,7 @@ def make_sharded_infer_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
         """One geometry lane on one shard: (Nmax, ...) -> (Nmax, out)."""
         pts = b["points"].astype(jnp.float32)
         s, r, em = multiscale_edges(pts, b["level_counts"], ms,
-                                    impl=knn_impl, interpret=interpret)
+                                    impl=knn_impl)
         em = em & b["send_ok"][s] & b["recv_ok"][r]
         s = jnp.where(em, s, 0)
         r = jnp.where(em, r, 0)
@@ -528,13 +522,14 @@ def make_sharded_infer_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
         return out[None]
 
     in_specs = (P(), {k: P(axis) for k in _BATCH_KEYS})
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=P(axis))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(axis))
     return jax.jit(fn) if jit else fn
 
 
 def make_sharded_rollout_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
                             steps: int, axis: str = "data",
-                            knn_impl: str = "xla", interpret: bool = True,
+                            knn_impl: str = "xla",
                             norm_in=None, norm_out=None, jit: bool = True,
                             pack_width: int = 1):
     """Sharded generate: graph-once, step-``steps`` under shard_map.
@@ -556,14 +551,14 @@ def make_sharded_rollout_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
     (a host-side halo exchange).
     """
     featurize = make_featurizer(cfg, norm_in=norm_in)
-    step = make_step_fn(cfg, norm_out=norm_out, interpret=interpret)
+    step = make_step_fn(cfg, norm_out=norm_out)
     ms = sspec.ms
     pack_width = int(pack_width)
 
     def one(params, b, state, remaining):
         pts = b["points"].astype(jnp.float32)
         s, r, em = multiscale_edges(pts, b["level_counts"], ms,
-                                    impl=knn_impl, interpret=interpret)
+                                    impl=knn_impl)
         em = em & b["send_ok"][s] & b["recv_ok"][r]
         s = jnp.where(em, s, 0)
         r = jnp.where(em, r, 0)
@@ -592,6 +587,6 @@ def make_sharded_rollout_fn(cfg: GNNConfig, sspec: ShardSpec, mesh, *,
         return out[None], rem2[None]
 
     in_specs = (P(), {k: P(axis) for k in _BATCH_KEYS}, P(axis), P(axis))
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=(P(axis), P(axis)))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=(P(axis), P(axis)))
     return jax.jit(fn) if jit else fn

@@ -78,8 +78,7 @@ def flash_attention(q, k, v, *, group_size: int = 1, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    block_k: int = DEFAULT_BLOCK_K):
     """q: (BH, Sq, hd); k, v: (BH // group_size, Skv, hd).
     Returns (BH, Sq, hd). Positions are 0-based within each tensor; causal
     masking assumes Sq == Skv (training/prefill self-attention)."""
@@ -111,5 +110,5 @@ def flash_attention(q, k, v, *, group_size: int = 1, causal: bool = True,
             pltpu.VMEM((bq, 128), jnp.float32),   # running max
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
         ],
-        interpret=interpret,
+        interpret=jax.default_backend() == "cpu",
     )(q, k, v)

@@ -49,12 +49,12 @@ def _knn_kernel(q_ref, cx_ref, cy_ref, cz_ref, cidx_ref, cvalid_ref,
 
 
 def knn_topk_call(q_pos4, cand_x, cand_y, cand_z, cand_idx, cand_valid,
-                  k: int, *, block_q: int = DEFAULT_BLOCK_Q,
-                  interpret: bool = True):
+                  k: int, *, block_q: int = DEFAULT_BLOCK_Q):
     """q_pos4: (N, 4) f32; cand_*: (N, C); N must be a multiple of block_q.
 
-    Returns (idx (N, k) i32, d2 (N, k) f32). ``interpret=True`` runs the
-    kernel body on CPU (this container has no TPU); pass False on TPU."""
+    Returns (idx (N, k) i32, d2 (N, k) f32). On the CPU backend the kernel
+    body runs in the Pallas interpreter; on an accelerator it compiles to a
+    Mosaic custom call."""
     n, c = cand_idx.shape
     assert n % block_q == 0, (n, block_q)
     grid = (n // block_q,)
@@ -72,5 +72,5 @@ def knn_topk_call(q_pos4, cand_x, cand_y, cand_z, cand_idx, cand_valid,
             jax.ShapeDtypeStruct((n, k), jnp.int32),
             jax.ShapeDtypeStruct((n, k), jnp.float32),
         ),
-        interpret=interpret,
+        interpret=jax.default_backend() == "cpu",
     )(q_pos4, cand_x, cand_y, cand_z, cand_idx, cand_valid)

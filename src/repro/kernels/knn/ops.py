@@ -19,8 +19,7 @@ def _round_up(x: int, m: int) -> int:
 
 
 def topk_neighbors(q_pos, cand_pos, cand_idx, cand_valid, k: int, *,
-                   impl: str = "xla", interpret: bool = True,
-                   block_q: int = DEFAULT_BLOCK_Q):
+                   impl: str = "xla", block_q: int = DEFAULT_BLOCK_Q):
     """Dispatch to the Pallas kernel or the XLA reference.
 
     q_pos: (N, 3); cand_pos: (N, C, 3); cand_idx: (N, C) i32 (safe values in
@@ -43,7 +42,7 @@ def topk_neighbors(q_pos, cand_pos, cand_idx, cand_valid, k: int, *,
     cv = jnp.pad(cand_valid.astype(jnp.float32),
                  ((0, n_pad - n), (0, c_pad - c)))
     idx, d2 = knn_topk_call(q4, cp[..., 0], cp[..., 1], cp[..., 2], ci, cv,
-                            k, block_q=block_q, interpret=interpret)
+                            k, block_q=block_q)
     idx, d2 = idx[:n], d2[:n]
     mask = idx >= 0
     return idx, d2, mask

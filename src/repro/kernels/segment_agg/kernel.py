@@ -39,13 +39,13 @@ def _agg_kernel(dest_ref, msg_ref, out_ref, *, block_n: int):
 
 def segment_agg_call(messages, dest_local, n_blocks: int,
                      *, block_n: int = DEFAULT_BLOCK_N,
-                     block_d: int = DEFAULT_BLOCK_D, interpret: bool = True):
+                     block_d: int = DEFAULT_BLOCK_D):
     """messages: (NB*EBLK, D) sorted+padded by ops.prepare(); dest_local:
     (NB*EBLK, 1) i32, destination row within each node block (-1 = padding).
     Returns (NB*block_n, D) scatter-add result.
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container has no TPU); on TPU pass interpret=False."""
+    On the CPU backend the kernel body runs in the Pallas interpreter; on
+    an accelerator it compiles to a Mosaic custom call."""
     e_pad, d = messages.shape
     assert e_pad % n_blocks == 0
     eblk = e_pad // n_blocks
@@ -61,5 +61,5 @@ def segment_agg_call(messages, dest_local, n_blocks: int,
         ],
         out_specs=pl.BlockSpec((block_n, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_blocks * block_n, d), messages.dtype),
-        interpret=interpret,
+        interpret=jax.default_backend() == "cpu",
     )(dest_local, messages)

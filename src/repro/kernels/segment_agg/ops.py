@@ -174,8 +174,7 @@ def segment_sum_sorted(messages, order, sorted_ids, num_segments: int):
 
 def segment_sum_prepared(prep: Union[SegmentPrep, DeviceSegmentPrep],
                          messages, *,
-                         block_d: int = DEFAULT_BLOCK_D,
-                         interpret: bool = True):
+                         block_d: int = DEFAULT_BLOCK_D):
     """messages: (E, D) -> (n_segments, D) scatter-add via the Pallas kernel.
 
     Accepts either preparer's output: host ``prepare()`` (numpy arrays) or
@@ -189,13 +188,12 @@ def segment_sum_prepared(prep: Union[SegmentPrep, DeviceSegmentPrep],
         gathered = jnp.pad(gathered, ((0, 0), (0, pad_d - d)))
     out = segment_agg_call(gathered, jnp.asarray(prep.dest_local),
                            prep.n_blocks, block_n=prep.block_n,
-                           block_d=min(block_d, pad_d), interpret=interpret)
+                           block_d=min(block_d, pad_d))
     return out[: prep.n_segments, :d]
 
 
-def segment_sum(messages, segment_ids, num_segments: int, *,
-                interpret: bool = True):
+def segment_sum(messages, segment_ids, num_segments: int):
     """Convenience one-shot API (does numpy prep; not jit-friendly —
     use prepare()/segment_sum_prepared() inside training loops)."""
     prep = prepare(np.asarray(segment_ids), num_segments)
-    return segment_sum_prepared(prep, messages, interpret=interpret)
+    return segment_sum_prepared(prep, messages)

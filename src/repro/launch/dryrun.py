@@ -42,7 +42,7 @@ from repro.configs import ASSIGNED_ARCHS, SHAPES, get_config
 from repro.configs.base import HW, ModelConfig, ShapeConfig
 from repro.launch import costmodel
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.optim.adam import AdamConfig, AdamState, adam_init, adam_update
 
@@ -258,7 +258,7 @@ def lower_step(cfg: ModelConfig, shape: ShapeConfig, mesh):
     bspecs = shd.batch_specs(cfg, shape, mesh, mode=mode)
     bsh = {k: NamedSharding(mesh, bspecs[k]) for k in batch}
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = make_train_step(api, cfg)
             opt_shape = jax.eval_shape(adam_init, params_shape)
@@ -424,7 +424,7 @@ def run_xmgn(multi_pod: bool) -> dict:
     params_shape = jax.eval_shape(
         lambda k: mgn_mod.init(k, cfg), jax.random.PRNGKey(0))
     t0 = time.time()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         lowered = grad_fn.lower(params_shape, stacked)
         compiled = lowered.compile()
     secs = time.time() - t0

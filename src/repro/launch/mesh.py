@@ -9,18 +9,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 names explicit/auto mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto
-    AxisType = None
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,7 +28,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
     dev = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(dev, axes, **_mesh_kwargs(len(axes)))
+    return Mesh(dev, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int | None = None, n_model: int = 1):
@@ -48,22 +37,7 @@ def make_host_mesh(n_data: int | None = None, n_model: int = 1):
     if n_data is None:
         n_data = n_dev // n_model
     return jax.make_mesh((n_data, n_model), ("data", "model"),
-                         **_mesh_kwargs(2))
-
-
-def mesh_context(mesh):
-    """Context manager activating ``mesh`` across jax versions.
-
-    jax >= 0.5 has ``jax.set_mesh``; some 0.4.x releases have
-    ``jax.sharding.use_mesh``; otherwise ``Mesh`` itself is a context
-    manager (the legacy global-mesh mechanism), which suffices for jits
-    whose shardings are passed explicitly via in_shardings."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh) -> tuple:

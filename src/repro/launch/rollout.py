@@ -210,17 +210,16 @@ class RolloutEngine:
             sspec = srv._calibrate_shard(size, ms)
             gen = sharded.make_sharded_rollout_fn(
                 cfg, sspec, srv._mesh, steps=self.steps_per_flush,
-                knn_impl=srv._knn_impl, interpret=srv._interpret,
+                knn_impl=srv._knn_impl,
                 norm_in=srv._norm_in, norm_out=srv._norm_out,
                 pack_width=self.slots)
             prefill = None
         else:
             prefill = make_prefill_fn(
-                cfg, ms, knn_impl=srv._knn_impl, interpret=srv._interpret,
-                norm_in=srv._norm_in)
+                cfg, ms, knn_impl=srv._knn_impl, norm_in=srv._norm_in)
             gen = make_generate_fn(
                 cfg, steps=self.steps_per_flush, norm_out=srv._norm_out,
-                interpret=srv._interpret, donate=srv._donate)
+                donate=srv._donate)
 
         def insert_tree(graph, state, new_graph, new_state, slot):
             if self.sharded_mode:

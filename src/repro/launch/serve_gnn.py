@@ -81,12 +81,13 @@ Sampling is deterministic per (server seed, request id): resubmitting a
 request id reproduces its point cloud bit-for-bit regardless of what other
 traffic (or warmup) ran before it.
 
-Cold start (``repro.ckpt.compile_cache`` / ``repro.ckpt.artifact``): with
-``cfg.compile_cache_dir`` / ``--compile-cache`` set, XLA compiles go through
-a persistent on-disk cache, so process restarts, autoscaler ladder growth
-and LRU evict→rebuild re-pay a millisecond disk load instead of the
-~0.5–2 s compile. Bucket calibration (the one host cKDTree use) is cached
-per size in ``_calib`` and survives eviction, so an evict→rebuild re-pays
+Cold start (``repro.ckpt.compile_cache`` / ``repro.ckpt.artifact``): the
+CLI always, and the library when ``cfg.compile_cache_dir`` is set, send XLA
+compiles through a persistent on-disk cache, so process restarts,
+autoscaler ladder growth and LRU evict→rebuild re-pay a millisecond disk
+load instead of the ~0.5–2 s compile. Bucket calibration (the one host
+cKDTree use) is cached per size in ``_calib`` and survives eviction, so an
+evict→rebuild re-pays
 at most a cache load — never recalibration. ``save_artifact``/
 ``from_artifact`` go further: the deploy artifact bundles params,
 normalizers, the learned ladder + request-size histogram, every calibrated
@@ -447,7 +448,6 @@ class GNNServer:
                  bucket_sizes: Union[str, Sequence[int]] = (1024,),
                  *, params=None, max_batch: int = 4, n_levels: int = 3,
                  knn_impl: str = "xla", agg_impl: Optional[str] = None,
-                 interpret: bool = True,
                  norm_in=None, norm_out=None, seed: int = 0,
                  reference=None, check_requests: bool = True,
                  reject_overflow: bool = False, shard_devices: int = 1,
@@ -461,7 +461,8 @@ class GNNServer:
                  _restore: Optional[dict] = None):
         # persistent XLA compile cache: recompiles of previously-seen bucket
         # programs (restart, ladder growth, LRU evict→rebuild) hit disk
-        compile_cache.enable(getattr(cfg, "compile_cache_dir", ""))
+        if cfg.compile_cache_dir:
+            compile_cache.enable(cfg.compile_cache_dir)
         if agg_impl is not None:
             cfg = cfg.replace(agg_impl=agg_impl)
         if cfg.agg_impl == "pallas" and int(shard_devices) == 1:
@@ -498,7 +499,6 @@ class GNNServer:
             jax.random.PRNGKey(seed), cfg)
         self.seed = int(seed)
         self._knn_impl = knn_impl
-        self._interpret = interpret
         self._norm_in = norm_in
         self._norm_out = norm_out
         self._donate = donate
@@ -676,8 +676,8 @@ class GNNServer:
                 return b
             shard_infer = sharded.make_sharded_infer_fn(
                 cfg, sspec, self._mesh, knn_impl=self._knn_impl,
-                interpret=self._interpret, norm_in=self._norm_in,
-                norm_out=self._norm_out, pack_width=self.max_batch)
+                norm_in=self._norm_in, norm_out=self._norm_out,
+                pack_width=self.max_batch)
             return Bucket(n_points=n, ms=ms, infer=None, sspec=sspec,
                           shard_infer=shard_infer,
                           plan_sig=sspec.signature())
@@ -691,7 +691,6 @@ class GNNServer:
                 self.stats.cache_loads += 1
             return b
         infer = make_batched_infer_fn(cfg, ms, knn_impl=self._knn_impl,
-                                      interpret=self._interpret,
                                       norm_in=self._norm_in,
                                       norm_out=self._norm_out,
                                       donate=self._donate)
@@ -798,22 +797,17 @@ class GNNServer:
                 if sharded_mode:
                     infer = sharded.make_sharded_infer_fn(
                         self.cfg, b.sspec, self._mesh,
-                        knn_impl=self._knn_impl, interpret=self._interpret,
-                        norm_in=self._norm_in, norm_out=self._norm_out,
-                        pack_width=self.max_batch)
+                        knn_impl=self._knn_impl, norm_in=self._norm_in,
+                        norm_out=self._norm_out, pack_width=self.max_batch)
                 else:
                     infer = make_batched_infer_fn(
                         self.cfg, b.ms, knn_impl=self._knn_impl,
-                        interpret=self._interpret, norm_in=self._norm_in,
-                        norm_out=self._norm_out, donate=self._donate)
+                        norm_in=self._norm_in, norm_out=self._norm_out,
+                        donate=self._donate)
             arg_specs = self._shard_arg_specs(n) if sharded_mode else \
                 self._bucket_arg_specs(n)
             try:
-                # bypass the persistent cache: a cache-loaded executable
-                # serializes a payload that cannot re-link — AOT export
-                # needs a genuinely fresh backend compile
-                with compile_cache.suspended():
-                    compiled = infer.lower(*arg_specs).compile()
+                compiled = infer.lower(*arg_specs).compile()
             except Exception as e:
                 log.warning("AOT lowering failed for bucket %d (%s: %s); "
                             "artifact will carry specs only for this size",
@@ -838,7 +832,6 @@ class GNNServer:
             "cfg": dataclasses.asdict(self.cfg),
             "knobs": {k: getattr(self, k) for k in self._ARTIFACT_KNOBS},
             "knn_impl": self._knn_impl,
-            "interpret": bool(self._interpret),
             "donate": bool(self._donate),
             "auto": bool(self.auto),
             "reference": {"verts": np.asarray(ref_verts, np.float32),
@@ -866,7 +859,7 @@ class GNNServer:
         triggers zero traces, zero XLA compiles and zero recalibration.
         Keyword overrides are honored, but overriding a knob that is baked
         into the compiled programs (``max_batch``, ``n_levels``,
-        ``knn_impl``, ``interpret``, ``donate``, or a different ``cfg``)
+        ``knn_impl``, ``donate``, or a different ``cfg``)
         drops the executables and falls back to jit + the persistent
         compilation cache.
         """
@@ -881,16 +874,15 @@ class GNNServer:
                     stored[k] = tuple(v)
             cfg = GNNConfig(**stored)
         knobs = dict(tree.get("knobs", {}))
-        for k in ("knn_impl", "interpret", "donate"):
+        for k in ("knn_impl", "donate"):
             knobs[k] = tree.get(k)
         if tree.get("auto"):
             cfg = cfg.replace(bucket_policy="auto")
         for k, v in kw.items():
-            if k in cls._AOT_KNOBS + ("knn_impl", "interpret", "donate") \
+            if k in cls._AOT_KNOBS + ("knn_impl", "donate") \
                     and v != knobs.get(k):
                 aot_valid = False
             knobs[k] = v
-        knobs["interpret"] = bool(knobs.get("interpret", True))
         knobs["donate"] = bool(knobs.get("donate", True))
 
         def norm_pair(d):
@@ -2104,9 +2096,11 @@ def main():
                     help="serve trained weights + normalizer stats from a "
                     "launch.train checkpoint")
     ap.add_argument("--compile-cache", default=None,
-                    help="persistent XLA compilation cache directory: "
-                    "recompiles of previously-seen bucket programs become "
-                    "disk loads across restarts / ladder growth / eviction")
+                    help="persistent XLA compilation cache directory "
+                    "(ignored when $JAX_COMPILATION_CACHE_DIR is set; "
+                    "default <checkout>/.jax_cache): recompiles of "
+                    "previously-seen bucket programs become disk loads "
+                    "across restarts / ladder growth / eviction")
     ap.add_argument("--save-artifact", default=None,
                     help="after serving, freeze the adapted server (ladder, "
                     "histogram, calibrated specs, AOT executables) into "
@@ -2159,6 +2153,7 @@ def main():
                     help="per-rollout end-to-end deadline in seconds "
                     "(0 = none)")
     args = ap.parse_args()
+    compile_cache.enable(args.compile_cache)
 
     cfg = GNNConfig()
     if args.reduced:
@@ -2172,8 +2167,6 @@ def main():
         cfg = cfg.replace(bucket_granularity=args.bucket_granularity)
     if args.refit_every is not None:
         cfg = cfg.replace(bucket_refit_every=args.refit_every)
-    if args.compile_cache:
-        cfg = cfg.replace(compile_cache_dir=args.compile_cache)
     if args.max_queue_depth is not None:
         cfg = cfg.replace(max_queue_depth=args.max_queue_depth)
     if args.shed_policy is not None:
@@ -2197,8 +2190,6 @@ def main():
               agg_impl=args.agg_impl, shard_devices=args.shard_devices,
               async_flush=not args.sync)
     if args.artifact:
-        # the artifact carries its own cfg; apply the CLI cache dir directly
-        compile_cache.enable(args.compile_cache)
         server = GNNServer.from_artifact(args.artifact, cfg=None,
                                          agg_impl=args.agg_impl,
                                          async_flush=not args.sync)
@@ -2218,8 +2209,11 @@ def main():
             print("autoscaling buckets: ladder derived from traffic "
                   "(no warmup compiles)")
         else:
-            print(f"warmup (compile {len(buckets)} buckets): "
-                  f"{time.perf_counter() - t0:.1f}s")
+            rep = server.stats.report()
+            print(f"warmup ({len(buckets)} buckets): "
+                  f"{time.perf_counter() - t0:.1f}s, "
+                  f"{rep['bucket_compiles']} compiles, "
+                  f"{rep['cache_loads']} compile-cache loads")
 
     rng = np.random.default_rng(1)
     req_sizes = (128, 192, 256) if auto else buckets

@@ -200,7 +200,8 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
         cfg = cfg.replace(graph_source=graph_source)
     # persistent XLA compile cache: a restarted/resumed trainer re-traces
     # its step program but loads the backend executable from disk
-    compile_cache.enable(getattr(cfg, "compile_cache_dir", ""))
+    if cfg.compile_cache_dir:
+        compile_cache.enable(cfg.compile_cache_dir)
     tel = telemetry if telemetry is not None else Telemetry.from_config(cfg)
     hists = _stage_hists(tel)
     loss_gauge = tel.metrics.gauge("train_loss",
@@ -483,9 +484,11 @@ def main():
                     "--steps (a resumed run keeps the checkpoint's horizon "
                     "by default)")
     ap.add_argument("--compile-cache", default=None,
-                    help="persistent XLA compilation cache directory: a "
-                    "restarted trainer loads its step program from disk "
-                    "instead of recompiling")
+                    help="persistent XLA compilation cache directory "
+                    "(ignored when $JAX_COMPILATION_CACHE_DIR is set; "
+                    "default <checkout>/.jax_cache): a restarted trainer "
+                    "loads its step program from disk instead of "
+                    "recompiling")
     ap.add_argument("--graph-source", choices=("host", "graphx"),
                     default=None,
                     help="training-graph build: host cKDTree or the "
@@ -507,6 +510,7 @@ def main():
                     "node features each step for rollout stability "
                     "(default: cfg.noise_std, i.e. off)")
     args = ap.parse_args()
+    compile_cache.enable(args.compile_cache)
     if args.arch == "xmgn-drivaer":
         cfg = get_config(args.arch)
         if args.reduced:
@@ -514,8 +518,6 @@ def main():
         if args.telemetry or args.trace_dir:
             cfg = cfg.replace(telemetry=True, trace_dir=args.trace_dir or "",
                               profile_capture=args.profile)
-        if args.compile_cache:
-            cfg = cfg.replace(compile_cache_dir=args.compile_cache)
         tel = Telemetry.from_config(cfg)
         with tel.capture():
             params, losses, (train, test, ni, no) = train_gnn(

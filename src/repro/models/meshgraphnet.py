@@ -44,8 +44,7 @@ def init(key, cfg: GNNConfig, dtype=jnp.float32):
     }
 
 
-def make_aggregator(receivers, n_nodes: int, agg_impl: str, *,
-                    interpret: bool = True):
+def make_aggregator(receivers, n_nodes: int, agg_impl: str):
     """Build ``agg(messages) -> (n_nodes, D)`` once per graph.
 
     The per-graph preprocessing (device argsort for ``'sorted'``, sorted
@@ -77,8 +76,7 @@ def make_aggregator(receivers, n_nodes: int, agg_impl: str, *,
                 prep.n_dropped > 0,
                 lambda m: jax.ops.segment_sum(m, receivers,
                                               num_segments=n_nodes),
-                lambda m: segops.segment_sum_prepared(
-                    prep, m, interpret=interpret),
+                lambda m: segops.segment_sum_prepared(prep, m),
                 msgs)
         return agg
     if agg_impl != "xla":
@@ -88,15 +86,28 @@ def make_aggregator(receivers, n_nodes: int, agg_impl: str, *,
                                             num_segments=n_nodes)
 
 
+def spread_padding(receivers, edge_mask, n_nodes: int):
+    """Receiver ids the Pallas aggregation packs by.
+
+    Padding edge slots all carry receiver 0 (the fixed-shape edge union's
+    convention), which would pile every masked slot into node block 0 and
+    overflow the static EBLK budget at real bucket sizes. Their messages
+    are zeroed before aggregation, so scatter them uniformly across
+    segments instead — zero contribution anywhere, and the packing budget
+    sees balanced load.
+    """
+    spread = jnp.arange(receivers.shape[0], dtype=receivers.dtype) % n_nodes
+    return jnp.where(edge_mask.astype(bool), receivers, spread)
+
+
 def apply(params, cfg: GNNConfig, node_feats, edge_feats, senders, receivers,
           edge_mask: Optional[jnp.ndarray] = None,
-          agg_impl: Optional[str] = None, interpret: bool = True):
+          agg_impl: Optional[str] = None):
     """Forward pass on one (sub)graph.
 
     node_feats: (N, node_in); edge_feats: (E, edge_in);
     senders/receivers: (E,) int32; edge_mask: (E,) 1.0 for real edges.
-    ``agg_impl`` overrides ``cfg.agg_impl`` (None -> use the config);
-    ``interpret`` only affects the Pallas aggregation path.
+    ``agg_impl`` overrides ``cfg.agg_impl`` (None -> use the config).
     Returns (N, node_out).
     """
     n_nodes = node_feats.shape[0]
@@ -104,17 +115,8 @@ def apply(params, cfg: GNNConfig, node_feats, edge_feats, senders, receivers,
     impl = agg_impl or cfg.agg_impl
     agg_receivers = receivers
     if impl == "pallas" and edge_mask is not None:
-        # padding edge slots all carry receiver 0 (the fixed-shape edge
-        # union's convention), which would pile every masked slot into node
-        # block 0 and overflow the static EBLK budget at real bucket sizes.
-        # Their messages are zeroed before aggregation, so scatter them
-        # uniformly across segments instead — zero contribution anywhere,
-        # and the packing budget sees balanced load.
-        n_edges = receivers.shape[0]
-        spread = (jnp.arange(n_edges, dtype=receivers.dtype) % n_nodes)
-        agg_receivers = jnp.where(edge_mask.astype(bool), receivers, spread)
-    aggregate = make_aggregator(agg_receivers, n_nodes, impl,
-                                interpret=interpret)
+        agg_receivers = spread_padding(receivers, edge_mask, n_nodes)
+    aggregate = make_aggregator(agg_receivers, n_nodes, impl)
     # named scopes label the HLO ops by model stage in jax.profiler captures
     with jax.named_scope("mgn/encode"):
         h = nn.mlp(params["node_encoder"], node_feats, act)
@@ -147,7 +149,7 @@ def apply(params, cfg: GNNConfig, node_feats, edge_feats, senders, receivers,
 
 def step(params, cfg: GNNConfig, node_feats, edge_feats, senders, receivers,
          state, *, edge_mask: Optional[jnp.ndarray] = None, out_stats=None,
-         agg_impl: Optional[str] = None, interpret: bool = True):
+         agg_impl: Optional[str] = None):
     """One autoregressive physics step: state (N, node_out) -> state'.
 
     The reusable core of both single-shot serving (T=1 from a zero state
@@ -170,7 +172,7 @@ def step(params, cfg: GNNConfig, node_feats, edge_feats, senders, receivers,
             s = (state - out_mu) / out_sd
         feats = jnp.concatenate([feats, s.astype(feats.dtype)], axis=-1)
     pred = apply(params, cfg, feats, edge_feats, senders, receivers,
-                 edge_mask=edge_mask, agg_impl=agg_impl, interpret=interpret)
+                 edge_mask=edge_mask, agg_impl=agg_impl)
     if out_stats is not None:
         out_mu, out_sd = out_stats
         pred = pred * out_sd + out_mu

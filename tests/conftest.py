@@ -9,6 +9,8 @@ examples instead of erroring at collection time.
 The stub covers exactly the API surface these tests use:
 ``given``, ``settings``, and ``strategies.{integers,booleans,sampled_from,
 floats}``. Real hypothesis, when installed, is always preferred.
+
+``cache_dir`` gives one test a persistent compilation cache.
 """
 from __future__ import annotations
 
@@ -16,6 +18,8 @@ import functools
 import random
 import sys
 import types
+
+import pytest
 
 try:  # pragma: no cover - exercised only when hypothesis is installed
     import hypothesis  # noqa: F401
@@ -91,3 +95,19 @@ except ImportError:
 
     sys.modules["hypothesis"] = _hyp
     sys.modules["hypothesis.strategies"] = _strategies
+
+
+@pytest.fixture
+def cache_dir(tmp_path, monkeypatch):
+    """A persistent compile cache for one test, switched off afterwards: the
+    cache is process-global JAX config and would turn later tests' compiles
+    in this worker into cache loads."""
+    import jax
+    from jax._src.compilation_cache import reset_cache
+
+    from repro.ckpt import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+    yield str(tmp_path / "xla-cache")
+    jax.config.update("jax_compilation_cache_dir", prev)
+    reset_cache()

@@ -120,12 +120,13 @@ def test_shard_spec_roundtrips_through_artifact():
 
 # ----------------------------------------------- in-process restore behavior
 
-def test_from_artifact_matches_source_server(tmp_path):
+def test_from_artifact_matches_source_server(tmp_path, cache_dir):
     verts, faces = _geom(2)
-    src = GNNServer(_cfg(), (128,), max_batch=2, seed=5)
+    src = GNNServer(_cfg(compile_cache_dir=cache_dir), (128,), max_batch=2,
+                    seed=5)
     [want] = src.serve([(verts, faces, 100)])
     path = str(tmp_path / "deploy.msgpack")
-    src.save_artifact(path)
+    info = src.save_artifact(path)
 
     dst = GNNServer.from_artifact(path)
     assert dst.max_batch == 2 and dst.seed == 5
@@ -134,20 +135,27 @@ def test_from_artifact_matches_source_server(tmp_path):
     np.testing.assert_allclose(got.fields, want.fields, atol=1e-5)
     rep = dst.stats.report()
     # in-process restore still compiles nothing: the bucket runs the
-    # artifact's deserialized AOT executable
+    # artifact's AOT executable where the backend could serialize one
+    # (not XLA:CPU), else a load from the persistent compilation cache
     assert rep["bucket_compiles"] == 0
     assert rep["bucket_calibrations"] == 0
     assert rep["cache_loads"] >= 1
-    assert dst._buckets[128].aot
+    assert dst._buckets[128].aot == (128 in info["aot_buckets"])
 
 
-def test_override_of_baked_knob_drops_aot(tmp_path):
+def test_override_of_baked_knob_drops_aot(tmp_path, monkeypatch):
+    # XLA:CPU cannot serialize executables: stand in a payload so the gate
+    # that drops AOT programs on a baked-knob override is exercised here
+    exe = object()
+    monkeypatch.setattr(artifact_lib, "serialize_compiled", lambda c: b"x")
+    monkeypatch.setattr(artifact_lib, "deserialize_compiled", lambda b: exe)
     verts, faces = _geom(2)
     src = GNNServer(_cfg(), (128,), max_batch=2, seed=5)
     src.serve([(verts, faces, 100)])
     path = str(tmp_path / "deploy.msgpack")
     info = src.save_artifact(path)
     assert info["aot_buckets"] == [128]
+    assert GNNServer.from_artifact(path)._aot == {128: exe}
 
     dst = GNNServer.from_artifact(path, max_batch=3)   # baked into programs
     assert dst.max_batch == 3

@@ -19,8 +19,6 @@ from repro.models import registry
 def _hlo_flops(fn, *args):
     c = jax.jit(fn).lower(*args).compile()
     ca = c.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax returns one dict per device
-        ca = ca[0] if ca else {}
     return float(ca.get("flops", 0.0))
 
 

@@ -73,8 +73,7 @@ def test_knn_pallas_kernel_matches_ref():
     cp = q[ci]
     args = (jnp.asarray(q), jnp.asarray(cp), jnp.asarray(ci), jnp.asarray(cv))
     i_ref, d_ref, m_ref = knn_ref.topk_neighbors(*args, k)
-    i_pl, d_pl, m_pl = knn_ops.topk_neighbors(*args, k, impl="pallas",
-                                              interpret=True)
+    i_pl, d_pl, m_pl = knn_ops.topk_neighbors(*args, k, impl="pallas")
     np.testing.assert_array_equal(np.asarray(i_ref), np.asarray(i_pl))
     np.testing.assert_allclose(np.asarray(d_ref), np.asarray(d_pl),
                                rtol=1e-6)
