@@ -142,9 +142,13 @@ log = logging.getLogger(__name__)
 
 # serving-lifecycle stages recorded per batch/request (see ServerStats
 # stage histograms + the per-request trace spans): submit -> queue_wait ->
-# bucket_route -> prepare -> dispatch -> device_wait -> harvest -> result
-SERVE_STAGES = ("queue_wait", "prepare", "dispatch", "device_wait",
-                "harvest", "compile", "cache_load")
+# bucket_route -> drain -> prepare (sample, check_cloud) -> dispatch ->
+# device_wait -> harvest -> publish -> result. ``starved`` is the time
+# from a device_wait that left no execution in flight to the next program
+# call: the device had nothing to run for at least that long.
+SERVE_STAGES = ("queue_wait", "drain", "prepare", "sample", "check_cloud",
+                "dispatch", "device_wait", "harvest", "publish", "starved",
+                "compile", "cache_load")
 
 
 def _level_sizes(n_points: int, n_levels: int) -> Tuple[int, ...]:
@@ -259,12 +263,16 @@ class ServerStats:
     worker_restarts: int = 0           # supervisor restarts after a crash
     quarantined_buckets: int = 0       # sizes pulled after build/compile fail
     bucket_fallbacks: int = 0          # batches served by a larger bucket
+    late_dispatches: int = 0           # program calls that found the previous
+                                       # execution already finished: the
+                                       # device idled for an unmeasured time
     lock: threading.Lock = field(default_factory=threading.Lock,
                                  repr=False, compare=False)
 
     _RESILIENCE = ("timed_out_requests", "rejected_overload",
                    "nonfinite_results", "worker_crashes", "worker_restarts",
                    "quarantined_buckets", "bucket_fallbacks")
+    _COUNTERS = _RESILIENCE + ("late_dispatches",)
 
     def __post_init__(self):
         self._recent_lat: deque = deque(maxlen=self.recent_cap)
@@ -283,11 +291,11 @@ class ServerStats:
             s: m.histogram(f"serve_{s}_seconds",
                            help=f"serving stage time: {s}")
             for s in SERVE_STAGES}
-        # resilience: counters monitors can alert on + health gauges
+        # counters monitors can alert on + health gauges
         self._counters = {
             name: m.counter(f"serve_{name}_total",
-                            help=f"resilience counter: {name}")
-            for name in self._RESILIENCE}
+                            help=f"serving counter: {name}")
+            for name in self._COUNTERS}
         self.g_worker_alive = m.gauge(
             "serve_worker_alive",
             help="1 while the background serve worker is running")
@@ -298,7 +306,8 @@ class ServerStats:
             help="unix time the worker last published results")
 
     def bump(self, name: str, n: int = 1):
-        """Increment a resilience counter (scalar field + Prometheus)."""
+        """Increment a counter of ``_COUNTERS`` (scalar field +
+        Prometheus)."""
         with self.lock:
             setattr(self, name, getattr(self, name) + n)
         self._counters[name].inc(n)
@@ -327,14 +336,18 @@ class ServerStats:
         with self.lock:
             self._recent_batch.append(int(size))
 
-    def record_stage(self, stage: str, dt_s: float):
-        """One observation of a lifecycle stage (see ``SERVE_STAGES``)."""
+    def stage_hist(self, stage: str):
+        """The ``serve_<stage>_seconds`` histogram (see ``SERVE_STAGES``)."""
         h = self._h_stage.get(stage)
         if h is None:
             h = self._h_stage[stage] = self.metrics.histogram(
                 f"serve_{stage}_seconds",
                 help=f"serving stage time: {stage}")
-        h.observe(dt_s)
+        return h
+
+    def record_stage(self, stage: str, dt_s: float):
+        """One observation of a lifecycle stage measured elsewhere."""
+        self.stage_hist(stage).observe(dt_s)
 
     def reset(self):
         """Zero every counter and histogram (keeps the lock and registry
@@ -353,7 +366,7 @@ class ServerStats:
             self.grown_buckets = 0
             self.padding_points = 0
             self.requested_points = 0
-            for name in self._RESILIENCE:
+            for name in self._COUNTERS:
                 setattr(self, name, 0)
             self._recent_lat.clear()
             self._recent_batch.clear()
@@ -391,7 +404,7 @@ class ServerStats:
                 "grown_buckets": self.grown_buckets,
             }
             counters.update({name: getattr(self, name)
-                             for name in self._RESILIENCE})
+                             for name in self._COUNTERS})
             padded = self.padding_points
             requested = self.requested_points
         n = self._h_latency.count
@@ -561,6 +574,11 @@ class GNNServer:
                                           # resolves to an immediate error
         self._restarts = 0
         self._rollout = None              # lazy RolloutEngine (rollout_engine)
+        # device-starved accounting (_call_compiled / _harvest): the output
+        # of the newest program call until it is harvested, and when the
+        # harvest that left no execution in flight ended
+        self._last_out = None
+        self._idle_since: Optional[float] = None
         self._mesh = (mesh_for_shards(self.shard_devices)
                       if self.shard_devices > 1 else None)
         # grid specs are calibrated from a reference geometry representative
@@ -758,6 +776,37 @@ class GNNServer:
         }
         return (p_sds, batch)
 
+    def compiled(self, n_points: int):
+        """The ``jax.stages.Compiled`` program of the live bucket of
+        ``n_points`` points, lowered from the bucket's own argument specs —
+        on a warm server a compile-cache hit, not a second compile.
+
+        Its ``as_text()`` names every HLO instruction with the named-scope
+        path it came from (``graphx/knn_edges``, ``mgn/message_passing``),
+        which a device trace's op events lack; ``memory_analysis()`` gives
+        the program's scratch, which ``memory_stats()`` peaks leave out.
+        Raises ``KeyError`` for a size with no live bucket.
+        """
+        b = self._buckets[n_points]
+        sharded_mode = b.sspec is not None
+        infer = b.shard_infer if sharded_mode else b.infer
+        if b.aot or not hasattr(infer, "lower"):
+            # the bucket itself runs a deserialized executable: rebuild
+            # the jittable fn just for lowering
+            if sharded_mode:
+                infer = sharded.make_sharded_infer_fn(
+                    self.cfg, b.sspec, self._mesh,
+                    knn_impl=self._knn_impl, norm_in=self._norm_in,
+                    norm_out=self._norm_out, pack_width=self.max_batch)
+            else:
+                infer = make_batched_infer_fn(
+                    self.cfg, b.ms, knn_impl=self._knn_impl,
+                    norm_in=self._norm_in, norm_out=self._norm_out,
+                    donate=self._donate)
+        arg_specs = self._shard_arg_specs(n_points) if sharded_mode else \
+            self._bucket_arg_specs(n_points)
+        return infer.lower(*arg_specs).compile()
+
     def save_artifact(self, path: str) -> dict:
         """Freeze this server's learned + compiled state into one file.
 
@@ -788,26 +837,8 @@ class GNNServer:
                 self._calibrate_shard(n, ms)
         aot: Dict[str, bytes] = {}
         for n in live:
-            b = self._buckets[n]
-            sharded_mode = self.shard_devices > 1
-            infer = b.shard_infer if sharded_mode else b.infer
-            if b.aot or not hasattr(infer, "lower"):
-                # the bucket itself runs a deserialized executable: rebuild
-                # the jittable fn just for lowering
-                if sharded_mode:
-                    infer = sharded.make_sharded_infer_fn(
-                        self.cfg, b.sspec, self._mesh,
-                        knn_impl=self._knn_impl, norm_in=self._norm_in,
-                        norm_out=self._norm_out, pack_width=self.max_batch)
-                else:
-                    infer = make_batched_infer_fn(
-                        self.cfg, b.ms, knn_impl=self._knn_impl,
-                        norm_in=self._norm_in, norm_out=self._norm_out,
-                        donate=self._donate)
-            arg_specs = self._shard_arg_specs(n) if sharded_mode else \
-                self._bucket_arg_specs(n)
             try:
-                compiled = infer.lower(*arg_specs).compile()
+                compiled = self.compiled(n)
             except Exception as e:
                 log.warning("AOT lowering failed for bucket %d (%s: %s); "
                             "artifact will carry specs only for this size",
@@ -1331,47 +1362,57 @@ class GNNServer:
 
     # ------------------------------------------- prepare / dispatch / harvest
 
+    def _stage(self, name: str, record: bool, reqs=(), **attrs):
+        """One worker stage through ``Telemetry.span``: the
+        ``serve_<name>_seconds`` histogram when ``record``; with telemetry
+        on, the tracer span ``name`` and the profiler annotation
+        ``serve/<name>``, carrying ``attrs`` and the ids of ``reqs``."""
+        if self.telemetry.enabled and reqs:
+            attrs["rids"] = " ".join(str(r.request_id) for r in reqs)
+        return self.telemetry.span(
+            name, scope="serve",
+            hist=self.stats.stage_hist(name) if record else None, **attrs)
+
     def _prepare(self, b: Bucket, reqs: List[Request], record: bool):
         """Host stage: sample surfaces + run OOD checks; resolve rejections.
 
         Pure host numpy — in the async flush this is the work that overlaps
         the previous batch's in-flight XLA call.
         """
-        t0 = time.perf_counter()
         results: List[Result] = []
         ok_reqs, samples = [], []
-        for req in reqs:
-            if (self.reject_overflow and req.n_points is not None
-                    and req.n_points > b.n_points):
-                # static-ladder oversize: reject instead of downsampling
-                # (under auto routing the bucket always fits the request)
-                results.append(self._reject(
-                    req, b.n_points,
-                    f"request for {req.n_points} points exceeds the "
-                    f"largest bucket ({b.n_points}) and reject_overflow "
-                    "is set; use bucket_sizes='auto' to grow the ladder",
-                    np.zeros((0, 3), np.float32), record))
-                continue
-            pts, nrm = self._sample(req, b.n_points)
-            dropped = 0
-            if record and self.check_requests:
-                dropped = self._check_cloud(b, pts, req.request_id)
-            if dropped and self.reject_overflow:
-                results.append(self._reject(
-                    req, b.n_points,
-                    f"grid overflow: {dropped} candidate slots "
-                    "dropped (geometry denser than calibration reference)",
-                    pts, record))
-                continue
-            ok_reqs.append(req)
-            samples.append((pts, nrm))
-        t1 = time.perf_counter()
-        if record:
-            self.stats.record_stage("prepare", t1 - t0)
-        if self.telemetry.enabled:
-            self.telemetry.tracer.record_span(
-                "prepare", t0, t1, bucket=b.n_points, batch=len(reqs),
-                ok=len(ok_reqs), rids=[r.request_id for r in reqs])
+        with self._stage("prepare", record, reqs, bucket=b.n_points,
+                         batch=len(reqs)) as st:
+            for req in reqs:
+                if (self.reject_overflow and req.n_points is not None
+                        and req.n_points > b.n_points):
+                    # static-ladder oversize: reject instead of downsampling
+                    # (under auto routing the bucket always fits the request)
+                    results.append(self._reject(
+                        req, b.n_points,
+                        f"request for {req.n_points} points exceeds the "
+                        f"largest bucket ({b.n_points}) and reject_overflow "
+                        "is set; use bucket_sizes='auto' to grow the ladder",
+                        np.zeros((0, 3), np.float32), record))
+                    continue
+                with self._stage("sample", record, bucket=b.n_points,
+                                 rid=req.request_id):
+                    pts, nrm = self._sample(req, b.n_points)
+                dropped = 0
+                if record and self.check_requests:
+                    with self._stage("check_cloud", True, bucket=b.n_points,
+                                     rid=req.request_id):
+                        dropped = self._check_cloud(b, pts, req.request_id)
+                if dropped and self.reject_overflow:
+                    results.append(self._reject(
+                        req, b.n_points,
+                        f"grid overflow: {dropped} candidate slots "
+                        "dropped (geometry denser than calibration "
+                        "reference)", pts, record))
+                    continue
+                ok_reqs.append(req)
+                samples.append((pts, nrm))
+            st.set(ok=len(ok_reqs))
         return results, ok_reqs, samples
 
     def _dispatch(self, b: Bucket, pre: List[Result], ok_reqs: List[Request],
@@ -1382,17 +1423,9 @@ class GNNServer:
         dispatch) so the caller can do host work for the next batch while
         this one runs.
         """
-        t0 = time.perf_counter()
-        with self.telemetry.annotate("serve/dispatch"):
-            fl = self._dispatch_inner(b, pre, ok_reqs, samples, record)
-        t1 = time.perf_counter()
-        if record and ok_reqs:
-            self.stats.record_stage("dispatch", t1 - t0)
-        if self.telemetry.enabled:
-            self.telemetry.tracer.record_span(
-                "dispatch", t0, t1, bucket=b.n_points, batch=len(ok_reqs),
-                rids=[r.request_id for r in ok_reqs])
-        return fl
+        with self._stage("dispatch", record and bool(ok_reqs), ok_reqs,
+                         bucket=b.n_points, batch=len(ok_reqs)):
+            return self._dispatch_inner(b, pre, ok_reqs, samples, record)
 
     def _dispatch_inner(self, b: Bucket, pre: List[Result],
                         ok_reqs: List[Request], samples,
@@ -1438,7 +1471,7 @@ class GNNServer:
             # the compiled program has a pack axis only when max_batch > 1
             dev_batch = pack.batch() if self.max_batch > 1 else \
                 plans[0].batch()
-            out = self._call_compiled(b, b.shard_infer, self.params,
+            out = self._call_compiled(b, b.shard_infer, record, self.params,
                                       shard_put(dev_batch, self._mesh))
             return _InFlight(bucket=b, results=pre, ok_reqs=reqs_kept,
                              out=out, pts=np.stack(kept_pts), record=record,
@@ -1457,12 +1490,12 @@ class GNNServer:
         # timeline, and donation lets XLA reuse the buffers (off-CPU)
         dev_pts = jax.device_put(pts)
         dev_nrm = jax.device_put(nrm)
-        out = self._call_compiled(b, b.infer, self.params, dev_pts, dev_nrm,
-                                  jnp.full((rows,), n, jnp.int32))
+        out = self._call_compiled(b, b.infer, record, self.params, dev_pts,
+                                  dev_nrm, jnp.full((rows,), n, jnp.int32))
         return _InFlight(bucket=b, results=pre, ok_reqs=ok_reqs, out=out,
                          pts=pts, record=record)
 
-    def _call_compiled(self, b: Bucket, fn, *args):
+    def _call_compiled(self, b: Bucket, fn, record: bool, *args):
         """Invoke a bucket's jitted program, counting ACTUAL compiles.
 
         jit tracing/compilation happens synchronously inside the call (the
@@ -1475,14 +1508,30 @@ class GNNServer:
         ``bucket_compiles`` (true compiles) vs ``cache_loads``, so a
         restarted server that re-traces everything but compiles nothing
         reports zero compiles.
+
+        Device-starved time is closed here (``record`` only): if the last
+        harvest left no execution in flight, the time since it ended is a
+        ``starved`` observation; if the previous execution is still
+        unharvested but already finished, the device went idle at some
+        unseen moment and the call counts as a ``late_dispatches``.
         """
         faults.fire("serve.compile")      # chaos: compile/OOM failure
         cache_size = getattr(fn, "_cache_size", None)
         before = cache_size() if cache_size is not None else None
         ev = compile_cache.CompileEvents() if before is not None else None
         t0 = time.perf_counter()
-        with self.telemetry.annotate(f"serve/call_b{b.n_points}"):
+        late = (record and self._idle_since is None
+                and self._last_out is not None
+                and all(x.is_ready()
+                        for x in jax.tree_util.tree_leaves(self._last_out)))
+        with self._stage(f"call_b{b.n_points}", False):
             out = fn(*args)
+        if record and self._idle_since is not None:
+            self.stats.record_stage("starved", t0 - self._idle_since)
+        elif late:
+            self.stats.bump("late_dispatches")
+        self._idle_since = None
+        self._last_out = out
         if before is not None:
             grew = cache_size() - before
             if grew > 0:
@@ -1521,111 +1570,73 @@ class GNNServer:
         The ``block_until_ready`` wall time is the ``device_wait`` stage —
         how long the host actually stalled on XLA (at steady state under
         the async flush this is the device-bound part of the pipeline);
-        everything after it (host gather/copy/bookkeeping) is ``harvest``.
+        everything after it (host copy and gather, nonfinite guard,
+        bookkeeping) is ``harvest``. A recorded harvest of the newest
+        program call leaves the device with nothing in flight: the
+        ``starved`` interval starts where its ``device_wait`` ends.
         """
         results = list(fl.results)
         if fl.out is None:
             return results
-        b, record = fl.bucket, fl.record
-        t0 = time.perf_counter()
-        with self.telemetry.annotate("serve/device_wait"):
+        b, record, ok_reqs = fl.bucket, fl.record, fl.ok_reqs
+        newest = fl.out is self._last_out
+        if newest:
+            self._last_out = None
+        with self._stage("device_wait", record, bucket=b.n_points,
+                         batch=len(ok_reqs)) as st:
             out_dev = jax.block_until_ready(fl.out)
-        t_sync = time.perf_counter()
-        if record:
-            self.stats.record_stage("device_wait", t_sync - t0)
-        tel_on = self.telemetry.enabled
-        tracer = self.telemetry.tracer
-        if tel_on:
-            tracer.record_span(
-                "device_wait", t0, t_sync, bucket=b.n_points,
-                batch=len(fl.ok_reqs))
-        out = np.asarray(out_dev)
-        out = faults.corrupt("serve.harvest", out)   # chaos: device garbage
-        guard = self.cfg.nonfinite_guard
-        if b.sspec is not None:
-            # the host-side gather back into one cloud per geometry is part
-            # of what the client waits for — stamp completion after it.
-            # A max_batch == 1 program has no pack axis: normalize so the
-            # PackPlan de-interleave handles both layouts.
-            if out.ndim == 3:
-                out = out[:, None]
-            fields_per_geo = fl.plan.gather(out)
+        if newest and record:
+            self._idle_since = st.t1
+        with self._stage("harvest", record, ok_reqs, bucket=b.n_points,
+                         batch=len(ok_reqs)):
+            out = np.asarray(out_dev)
+            out = faults.corrupt("serve.harvest", out)  # chaos: garbage
+            if b.sspec is not None:
+                # the host-side gather back into one cloud per geometry is
+                # part of what the client waits for. A max_batch == 1
+                # program has no pack axis: normalize so the PackPlan
+                # de-interleave handles both layouts.
+                if out.ndim == 3:
+                    out = out[:, None]
+                out = fl.plan.gather(out)
+                # empty pack lanes replay the last geometry (PackPlan.batch)
+                replay = (fl.plan.width - len(ok_reqs)
+                          if self.max_batch > 1 else 0)
+            else:
+                # partial microbatches replay the last request to fill
+                # max_batch rows (_dispatch)
+                replay = max(self.max_batch, len(ok_reqs)) - len(ok_reqs)
             t_done = time.perf_counter()
+            guard = self.cfg.nonfinite_guard
             lats = []
-            for i, (req, fields) in enumerate(zip(fl.ok_reqs,
-                                                  fields_per_geo)):
-                if guard and not np.isfinite(fields).all():
-                    # contained per lane: one geometry's garbage never
-                    # rejects its pack neighbors
-                    results.append(self._nonfinite_result(b, req, fields))
+            for i, req in enumerate(ok_reqs):
+                if guard and not np.isfinite(out[i]).all():
+                    # nonfinite garbage never reaches a client as data —
+                    # the per-ITEM scan contains the blast radius to this
+                    # request (one pack lane's garbage spares its neighbors)
+                    results.append(self._nonfinite_result(b, req, out[i]))
                     continue
                 lat = t_done - (req.t_submit or t_done)
-                lats.append((req, lat))
+                lats.append(lat)
                 results.append(Result(
                     request_id=req.request_id, points=fl.pts[i],
-                    fields=fields, latency_s=lat, bucket=b.n_points,
-                    batch_size=len(fl.ok_reqs)))
-                if tel_on:
-                    tracer.record_span("request", req.t_submit or t_done,
-                                       t_done,
-                                       trace_id=f"req-{req.request_id}",
-                                       bucket=b.n_points)
-            if record and fl.ok_reqs:
-                padding = [self._padding_of(b, req) for req in fl.ok_reqs]
-                # empty pack lanes replay the last geometry (PackPlan.batch)
-                # — discarded compute, so it is padding waste too
-                replay = (fl.plan.width - len(fl.ok_reqs)
-                          if self.max_batch > 1 else 0)
-                for _, lat in lats:
+                    fields=out[i], latency_s=lat, bucket=b.n_points,
+                    batch_size=len(ok_reqs)))
+                if self.telemetry.enabled:
+                    self.telemetry.tracer.record_span(
+                        "request", req.t_submit or t_done, t_done,
+                        trace_id=f"req-{req.request_id}", bucket=b.n_points)
+            if record and ok_reqs:
+                padding = [self._padding_of(b, req) for req in ok_reqs]
+                for lat in lats:
                     self.stats.record_latency(lat)
-                self.stats.record_batch(len(fl.ok_reqs))
-                self.stats.record_stage("harvest", t_done - t_sync)
+                self.stats.record_batch(len(ok_reqs))
+                # replayed rows or lanes are discarded compute: waste too
                 with self.stats.lock:
                     self.stats.requested_points += sum(a for a, _ in padding)
                     self.stats.padding_points += \
                         sum(w for _, w in padding) + replay * b.n_points
-                b.served += len(fl.ok_reqs)
-            if tel_on:
-                tracer.record_span("harvest", t_sync, t_done,
-                                   bucket=b.n_points,
-                                   batch=len(fl.ok_reqs))
-            return results
-        t_done = time.perf_counter()
-        lats = []
-        for i, req in enumerate(fl.ok_reqs):
-            if guard and not np.isfinite(out[i]).all():
-                # nonfinite garbage never reaches a client as data — the
-                # per-ITEM scan contains the blast radius to this request
-                results.append(self._nonfinite_result(b, req, out[i]))
-                continue
-            lat = t_done - (req.t_submit or t_done)
-            lats.append(lat)
-            results.append(Result(request_id=req.request_id, points=fl.pts[i],
-                                  fields=out[i], latency_s=lat,
-                                  bucket=b.n_points,
-                                  batch_size=len(fl.ok_reqs)))
-            if tel_on:
-                tracer.record_span("request", req.t_submit or t_done,
-                                   t_done, trace_id=f"req-{req.request_id}",
-                                   bucket=b.n_points)
-        if tel_on:
-            tracer.record_span("harvest", t_sync, t_done,
-                               bucket=b.n_points, batch=len(fl.ok_reqs))
-        if record and fl.ok_reqs:
-            padding = [self._padding_of(b, req) for req in fl.ok_reqs]
-            # partial microbatches replay the last request to fill max_batch
-            # rows (_dispatch): that compute is discarded, so it is waste too
-            replay_rows = max(self.max_batch, len(fl.ok_reqs)) - \
-                len(fl.ok_reqs)
-            for lat in lats:
-                self.stats.record_latency(lat)
-            self.stats.record_batch(len(fl.ok_reqs))
-            self.stats.record_stage("harvest", t_done - t_sync)
-            with self.stats.lock:
-                self.stats.requested_points += sum(a for a, _ in padding)
-                self.stats.padding_points += sum(w for _, w in padding) + \
-                    replay_rows * b.n_points
-            b.served += len(fl.ok_reqs)
+                b.served += len(ok_reqs)
         return results
 
     def _run_batch(self, b: Bucket, reqs: List[Request],
@@ -2011,7 +2022,7 @@ class GNNServer:
 
     def _publish(self, results: List[Result]):
         """Land finished results in the buffer and wake waiters."""
-        with self._cond:
+        with self._stage("publish", True, results=len(results)), self._cond:
             for r in results:
                 self._done[r.request_id] = r
             self._inflight = []
@@ -2027,35 +2038,42 @@ class GNNServer:
             self.stats.g_last_flush.set(time.time())
             self._cond.notify_all()
 
-    def _serve_loop(self):
+    def _await_plan(self):
+        """Block until work is due, then pop it: ``(plan, expired)``, or
+        None once stopped with nothing left. Caller holds ``_cond``."""
         while True:
-            faults.fire("serve.worker")        # chaos: worker crash
-            with self._cond:
-                plan, expired = self._drain_plan(
-                    ready_only=not self._stop_flag)
-                if not plan and not expired:
-                    if self._stop_flag:
-                        return
-                    # sleep until the oldest pending request would trip the
-                    # flush deadline, or the earliest per-request deadline
-                    # would expire (or a submit/stop notification)
-                    now = time.perf_counter()
-                    oldest = min((q[0].t_submit
-                                  for q in self._queues.values() if q),
-                                 default=None)
-                    wakes = []
-                    if oldest is not None:
-                        wakes.append(self._deadline_s - (now - oldest))
-                    wakes.extend(r.deadline - now
-                                 for q in self._queues.values() for r in q
-                                 if r.deadline is not None)
-                    wait = max(min(wakes), 1e-4) if wakes else None
-                    self._cond.wait(timeout=wait)
-                    continue
+            plan, expired = self._drain_plan(ready_only=not self._stop_flag)
+            if plan or expired:
                 # requests leave the queues here; until their results are
                 # published they are "in flight" — a crash between drain
                 # and publish resolves them via _fail_pending
                 self._inflight = [req for _, batch in plan for req in batch]
+                return plan, expired
+            if self._stop_flag:
+                return None
+            # sleep until the oldest pending request would trip the flush
+            # deadline, or the earliest per-request deadline would expire
+            # (or a submit/stop notification)
+            now = time.perf_counter()
+            oldest = min((q[0].t_submit for q in self._queues.values() if q),
+                         default=None)
+            wakes = []
+            if oldest is not None:
+                wakes.append(self._deadline_s - (now - oldest))
+            wakes.extend(r.deadline - now
+                         for q in self._queues.values() for r in q
+                         if r.deadline is not None)
+            wait = max(min(wakes), 1e-4) if wakes else None
+            self._cond.wait(timeout=wait)
+
+    def _serve_loop(self):
+        while True:
+            faults.fire("serve.worker")        # chaos: worker crash
+            with self._stage("drain", True), self._cond:
+                work = self._await_plan()
+            if work is None:
+                return
+            plan, expired = work
             results = [self._timeout_result(n, req) for n, req in expired]
             # per-item errors become error Results inside _run_plan; the
             # outer except is a last resort so an infrastructural failure

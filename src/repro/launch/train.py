@@ -208,17 +208,13 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
                                    help="most recent training loss")
     steps_ctr = tel.metrics.counter("train_steps_total",
                                     help="optimizer steps taken")
-    with tel.span("data", n_samples=n_samples), \
-            tel.annotate("train/build_dataset"):
-        t0 = time.perf_counter()
+    with tel.span("data", scope="train", hist=hists["data"],
+                  n_samples=n_samples):
         train, test, norm_in, norm_out = pipe.build_dataset(cfg, n_samples)
-        hists["data"].observe(time.perf_counter() - t0)
     # one partitioning pass per sample + common padding so one jit covers all
-    with tel.span("partition", n_samples=len(train)), \
-            tel.annotate("train/partition"):
-        t0 = time.perf_counter()
+    with tel.span("partition", scope="train", hist=hists["partition"],
+                  n_samples=len(train)):
         psamples = pipe.partition_samples(cfg, train, norm_in, norm_out)
-        hists["partition"].observe(time.perf_counter() - t0)
 
     params = mgn.init(jax.random.PRNGKey(0), cfg)
     start_step = 0
@@ -285,9 +281,9 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
         # single-accelerator mode. Indexing by the GLOBAL step keeps the
         # sample sequence identical across a crash+resume.
         t0 = time.time()
-        with tel.span("step", trace_id=f"step-{it}", it=it):
+        with tel.span("step", trace_id=f"step-{it}", scope="train", it=it):
             tp0 = time.perf_counter()
-            with tel.span("prepare"):
+            with tel.span("prepare", scope="train"):
                 stacked, denom = prepare_gnn_batch(
                     psamples[it % len(psamples)], mesh)
                 if faults.active():
@@ -308,11 +304,9 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
                         nf + nrng.standard_normal(
                             nf.shape).astype(nf.dtype) * noise_std)
             tp1 = time.perf_counter()
-            first = it == start_step
-            with tel.annotate(f"train/step{'_first' if first else ''}"):
-                params, opt, loss, gnorm, skipped = step_fn(
-                    params, opt, stacked, denom)
-                losses.append(float(loss))  # blocks until the step finishes
+            params, opt, loss, gnorm, skipped = step_fn(
+                params, opt, stacked, denom)
+            losses.append(float(loss))      # blocks until the step finishes
             if bool(skipped):
                 nonfinite_steps += 1
                 skip_ctr.inc()
@@ -332,7 +326,7 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
             # keep_ckpts > 0 periodic saves go to step-tagged siblings
             # (<path>.stepNNNNNNNN) and the window is pruned — a corrupt
             # newest file leaves an intact fallback for --resume.
-            with tel.span("checkpoint", path=ckpt_path, it=it):
+            with tel.span("checkpoint", scope="train", path=ckpt_path, it=it):
                 if keep_ckpts > 0:
                     writer.save(ckpt.retained_path(ckpt_path, it + 1),
                                 ckpt_tree(params, opt, it + 1))
@@ -356,10 +350,9 @@ def train_gnn(cfg: GNNConfig, steps: int, n_samples: int,
                   f"gnorm {float(gnorm):.3f} ({timing})", flush=True)
     writer.wait()                          # surface any background failure
     if ckpt_path:
-        with tel.span("checkpoint", path=ckpt_path):
-            t0 = time.perf_counter()
+        with tel.span("checkpoint", scope="train", hist=hists["checkpoint"],
+                      path=ckpt_path):
             ckpt.save(ckpt_path, ckpt_tree(params, opt, steps))
-            hists["checkpoint"].observe(time.perf_counter() - t0)
     return params, losses, (train, test, norm_in, norm_out)
 
 
@@ -527,13 +520,10 @@ def main():
                 ckpt_every=args.ckpt_every, resume=args.resume,
                 opt_total_steps=args.total_steps,
                 keep_ckpts=args.keep_ckpts, noise_std=args.noise_std)
-            with tel.span("eval", n_samples=len(test)):
-                t0 = time.perf_counter()
-                metrics = eval_gnn(cfg, params, test, ni, no)
-                tel.metrics.histogram(
+            with tel.span("eval", scope="train", hist=tel.metrics.histogram(
                     "train_stage_eval_seconds",
-                    help="wall seconds spent in the 'eval' training stage",
-                ).observe(time.perf_counter() - t0)
+                    help="wall seconds spent in the 'eval' training stage")):
+                metrics = eval_gnn(cfg, params, test, ni, no)
         print(json.dumps(metrics, indent=2))
         if args.trace_dir:
             paths = tel.export()

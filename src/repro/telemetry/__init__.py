@@ -7,8 +7,8 @@ surface:
   per-request ``trace_id``; JSONL + Chrome ``trace_event`` exporters.
 * :class:`~repro.telemetry.metrics.MetricsRegistry` — counters, gauges,
   fixed-bucket streaming histograms; Prometheus text + JSON snapshots.
-* :mod:`~repro.telemetry.profiler` — ``TraceAnnotation`` wrappers, opt-in
-  ``jax.profiler.trace`` capture, device-memory snapshots.
+* :mod:`~repro.telemetry.profiler` — opt-in ``jax.profiler.trace``
+  capture, device-memory snapshots.
 
 The :class:`Telemetry` bundle is what call sites thread around: built from
 ``GNNConfig.telemetry`` / ``trace_dir`` knobs (or explicitly), it carries a
@@ -17,29 +17,85 @@ pays nothing for instrumentation it is not using (bound pinned by
 ``tests/test_telemetry.py``). The metrics registry is *always* live: it is
 the bounded-memory backing store for ``ServerStats`` and costs O(1) per
 observation.
+
+:meth:`Telemetry.span` is the one way a stage is recorded: one clock read
+on entry and one on exit feed a stage histogram, a tracer span and a
+``jax.profiler.TraceAnnotation`` — the last puts the stage on the
+profiler's clock, beside the device's ops in a captured trace.
 """
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional
+
+import jax
 
 from repro.telemetry.metrics import (Counter, Gauge, Histogram,
                                      MetricsRegistry, SnapshotWriter,
                                      default_latency_buckets,
                                      default_size_buckets)
-from repro.telemetry.trace import (NULL_TRACER, NullTracer, SpanRecord,
-                                   Tracer, check_well_nested, make_tracer)
+from repro.telemetry.trace import (_NULL_SPAN, NULL_TRACER, NullTracer,
+                                   SpanRecord, Tracer, check_well_nested,
+                                   make_tracer)
 from repro.telemetry import profiler
-from repro.telemetry.profiler import (annotate, device_memory_snapshot,
-                                      trace_capture, warn_once)
+from repro.telemetry.profiler import (device_memory_snapshot, trace_capture,
+                                      warn_once)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "SnapshotWriter",
     "Tracer", "NullTracer", "NULL_TRACER", "SpanRecord", "Telemetry",
-    "make_tracer", "check_well_nested", "annotate", "trace_capture",
+    "make_tracer", "check_well_nested", "trace_capture",
     "device_memory_snapshot", "warn_once", "profiler",
     "default_latency_buckets", "default_size_buckets",
 ]
+
+
+class _Stage:
+    """One stage through up to three sinks on one pair of clock reads.
+
+    ``hist`` observes the duration, ``span`` (an unopened tracer span)
+    records the interval and a ``jax.profiler.TraceAnnotation`` named
+    ``annotation`` (with ``attrs`` as its metadata) encloses it on the
+    profiler's clock; each is skipped when None. ``t0``/``t1`` keep the two
+    reads for callers that need the interval's ends.
+    """
+    __slots__ = ("_hist", "_span", "_annotation", "_attrs", "_ann",
+                 "t0", "t1")
+
+    def __init__(self, hist, span=None, annotation=None, attrs=None):
+        self._hist = hist
+        self._span = span
+        self._annotation = annotation
+        self._attrs = attrs
+        self._ann = None
+        self.t0 = self.t1 = None
+
+    def set(self, **attrs):
+        """Attach attributes discovered mid-stage to the tracer span."""
+        if self._span is not None:
+            self._span.set(**attrs)
+        return self
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._ann = jax.profiler.TraceAnnotation(self._annotation,
+                                                     **self._attrs)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        if self._span is not None:
+            self._span.open(self.t0)
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = time.perf_counter()
+        if self._span is not None:
+            self._span.close(self.t1)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._hist is not None:
+            self._hist.observe(self.t1 - self.t0)
+        return False
 
 
 class Telemetry:
@@ -78,15 +134,29 @@ class Telemetry:
 
     # ------------------------------------------------------------- tracing
 
-    def span(self, name: str, trace_id: Optional[str] = None, **attrs):
-        return self.tracer.span(name, trace_id=trace_id, **attrs)
+    def span(self, name: str, trace_id: Optional[str] = None, *,
+             scope: Optional[str] = None, hist: Optional[Histogram] = None,
+             **attrs):
+        """A stage as a context manager: ``with tel.span("prepare",
+        scope="serve", hist=h, bucket=256):``.
+
+        One ``perf_counter`` read on entry and one on exit feed every sink:
+        ``hist`` (always, when given) observes the duration; with telemetry
+        on, the tracer records a span ``name`` with ``attrs``, and a
+        ``scope`` opens a ``jax.profiler.TraceAnnotation`` named
+        ``<scope>/<name>`` with ``attrs`` as its metadata (so give only
+        strings and numbers). With telemetry off and no ``hist`` this is the
+        shared no-op span; with a ``hist`` it costs two clock reads and one
+        observation, and builds no annotation.
+        """
+        if not self.enabled:
+            return _NULL_SPAN if hist is None else _Stage(hist)
+        return _Stage(hist, self.tracer.span(name, trace_id=trace_id,
+                                             **attrs),
+                      f"{scope}/{name}" if scope else None, attrs)
 
     def trace(self, trace_id: Optional[str]):
         return self.tracer.trace(trace_id)
-
-    def annotate(self, name: str):
-        """Host-side XLA-profiler region (no-op when telemetry is off)."""
-        return annotate(name, enabled=self.enabled)
 
     def capture(self):
         """Opt-in full ``jax.profiler`` capture for a ``with`` region."""

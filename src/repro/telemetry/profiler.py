@@ -1,14 +1,15 @@
-"""JAX profiler integration: trace annotations, capture, memory snapshots.
+"""JAX profiler integration: capture and memory snapshots.
 
-Three hooks, all opt-in and all safe to call when telemetry is disabled:
+Host-side stages reach a captured profile as ``TraceAnnotation`` regions
+through :meth:`repro.telemetry.Telemetry.span`. Inside jitted code
+``jax.named_scope`` labels instead — an annotation there would time
+*tracing*, not execution; named scopes ride into the HLO metadata and label
+the compiled program's ops in the profile. The graphx pipeline and the
+MeshGraphNet processor carry those scopes (``graphx/knn_edges``,
+``graphx/featurize``, ``mgn/message_passing``...).
 
-* :func:`annotate` — host-side ``jax.profiler.TraceAnnotation`` context
-  manager (shows up as a named region in a captured XLA profile). Inside
-  jitted code use ``jax.named_scope`` instead — an annotation there would
-  time *tracing*, not execution; named scopes ride into the HLO metadata
-  and label the compiled program's ops in the profile. The graphx pipeline
-  and the MeshGraphNet processor carry those scopes
-  (``graphx/knn_edges``, ``graphx/featurize``, ``mgn/message_passing``...).
+Two hooks, both opt-in and safe to call when telemetry is disabled:
+
 * :func:`trace_capture` — wraps ``jax.profiler.trace(log_dir)`` so a
   serving run / training run can drop a full XLA profile under
   ``<trace_dir>/jax_profile`` when the capture flag is set; a no-op
@@ -28,20 +29,6 @@ from typing import Optional
 import jax
 
 log = logging.getLogger(__name__)
-
-
-def annotate(name: str, enabled: bool = True):
-    """Named host-side region for the XLA profiler timeline.
-
-    Returns a ``TraceAnnotation`` context manager when enabled, a shared
-    nullcontext otherwise — call sites stay unconditional.
-    """
-    if not enabled:
-        return contextlib.nullcontext()
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:                      # stripped/old runtime
-        return contextlib.nullcontext()
 
 
 @contextlib.contextmanager

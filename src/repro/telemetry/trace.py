@@ -107,6 +107,15 @@ class _ActiveSpan:
         return self
 
     def __enter__(self):
+        return self.open(self._tracer._now())
+
+    def __exit__(self, *exc):
+        self.close(self._tracer._now())
+        return False
+
+    def open(self, t0: float):
+        """Enter the span at ``t0``, a clock read the caller already took
+        (``Telemetry.span`` shares one read with its other sinks)."""
         tr = self._tracer
         stack = tr._stack()
         if stack:
@@ -117,12 +126,12 @@ class _ActiveSpan:
         if self.trace_id is None:
             self.trace_id = getattr(tr._local, "trace_id", None)
         stack.append(self)
-        self._t0 = tr._now()
+        self._t0 = t0
         return self
 
-    def __exit__(self, *exc):
+    def close(self, t1: float):
+        """Leave the span at ``t1`` and record it."""
         tr = self._tracer
-        t1 = tr._now()
         stack = tr._stack()
         # tolerate exception-driven unwinding out of order: pop through us
         while stack and stack[-1] is not self:
@@ -135,7 +144,6 @@ class _ActiveSpan:
             thread_id=threading.get_ident(),
             thread_name=threading.current_thread().name,
             trace_id=self.trace_id, attrs=self.attrs))
-        return False
 
 
 class _TraceContext:

@@ -273,8 +273,9 @@ def test_telemetry_bundle_disabled_is_null():
     assert not tel.enabled
     assert tel.tracer is NULL_TRACER
     assert tel.span("x") is _NULL_SPAN
-    # annotate degrades to a nullcontext-like CM
-    with tel.annotate("region"):
+    # a scoped stage with nothing to record is the same shared no-op
+    assert tel.span("region", scope="serve") is _NULL_SPAN
+    with tel.span("region", scope="serve"):
         pass
     with tel.capture():                    # no trace_dir: no-op
         pass
@@ -433,3 +434,163 @@ def test_server_telemetry_end_to_end(tmp_path):
     assert len(chrome["traceEvents"]) > len(recs)   # + thread metadata
     prom = open(paths["metrics_prom"]).read()
     assert "serve_request_latency_seconds_count" in prom
+
+
+# ------------------------------------------------ one stage, three sinks
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+        _FakeAnnotation.log.append(("new", name, kw))
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.name))
+        return False
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    import jax
+    _FakeAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+def test_span_one_clock_read_per_side_feeds_three_sinks(fake_annotation):
+    tel = Telemetry(enabled=True)
+    h = tel.metrics.histogram("serve_prepare_seconds")
+    with tel.span("prepare", scope="serve", hist=h, bucket=256,
+                  rids="3 4") as st:
+        with tel.span("sample", scope="serve", rid=3):
+            time.sleep(0.002)
+    [inner, outer] = tel.tracer.records()
+    assert (outer.name, inner.name) == ("prepare", "sample")
+    assert inner.parent_id == outer.span_id
+    # the span and the histogram carry the stage's own two clock reads
+    assert (outer.t_start, outer.t_end) == (st.t0, st.t1)
+    assert h.count == 1 and h.sum == outer.duration_s
+    assert outer.attrs == {"bucket": 256, "rids": "3 4"}
+    assert fake_annotation == [
+        ("new", "serve/prepare", {"bucket": 256, "rids": "3 4"}),
+        ("enter", "serve/prepare"),
+        ("new", "serve/sample", {"rid": 3}), ("enter", "serve/sample"),
+        ("exit", "serve/sample"), ("exit", "serve/prepare")]
+
+
+def test_span_disabled_observes_without_annotation(fake_annotation):
+    tel = Telemetry.disabled()
+    h = tel.metrics.histogram("serve_prepare_seconds")
+    with tel.span("prepare", scope="serve", hist=h, bucket=256) as st:
+        pass
+    assert h.count == 1 and h.sum == st.t1 - st.t0
+    assert fake_annotation == []
+    # without a scope an enabled span is a tracer span only
+    on = Telemetry(enabled=True)
+    with on.span("flush", items=2):
+        pass
+    assert fake_annotation == []
+    assert [r.name for r in on.tracer.records()] == ["flush"]
+
+
+def _stage_server(telemetry: bool, **kw):
+    from repro.configs.base import GNNConfig
+    from repro.launch.serve_gnn import GNNServer
+    cfg = GNNConfig().reduced().replace(levels=(64, 128, 256),
+                                        telemetry=telemetry)
+    return GNNServer(cfg, (128,), max_batch=1, seed=0, **kw)
+
+
+def test_server_stages_share_one_name_across_sinks(fake_annotation):
+    """Every worker stage reaches the stats histogram, the tracer and the
+    profiler annotation under one name; sample and check_cloud nest in
+    prepare."""
+    from repro.data import geometry as geo
+    srv = _stage_server(True)
+    verts, faces = geo.car_surface(geo.sample_params(0))
+    srv.start(deadline_s=0.005)
+    try:
+        rids = [srv.submit(verts, faces, 128) for _ in range(2)]
+        assert all(srv.result(r, timeout=120).error is None for r in rids)
+    finally:
+        srv.stop()
+    stages = ("drain", "prepare", "sample", "check_cloud", "dispatch",
+              "device_wait", "harvest", "publish")
+    rep = srv.stats.report()["stages"]
+    recs = srv.telemetry.tracer.records()
+    names = {r.name for r in recs}
+    opened = {name for ev, name, *_ in fake_annotation if ev == "new"}
+    for s in stages:
+        assert rep[s]["count"] > 0, s
+        assert s in names, s
+        assert f"serve/{s}" in opened, s
+    assert "serve/call_b128" in opened
+    by_id = {r.span_id: r for r in recs}
+    for r in recs:
+        if r.name in ("sample", "check_cloud"):
+            assert by_id[r.parent_id].name == "prepare", r
+    assert check_well_nested(recs) == []
+    # annotation metadata: cheap scalars, the request ids as one string
+    prep = [kw for ev, name, *kw in fake_annotation
+            if ev == "new" and name == "serve/prepare"]
+    assert all(set(kw[0]) == {"bucket", "batch", "rids"} for kw in prep)
+
+
+def test_server_stages_build_no_annotation_when_off(fake_annotation):
+    from repro.data import geometry as geo
+    srv = _stage_server(False)
+    verts, faces = geo.car_surface(geo.sample_params(0))
+    srv.serve([(verts, faces, 128)] * 2)
+    assert fake_annotation == []
+    rep = srv.stats.report()["stages"]
+    assert rep["prepare"]["count"] == 2 and rep["check_cloud"]["count"] == 2
+
+
+def test_starved_counts_the_gap_between_synchronous_plans():
+    from repro.data import geometry as geo
+    srv = _stage_server(False, async_flush=False)
+    srv.warmup()
+    verts, faces = geo.car_surface(geo.sample_params(0))
+    srv.serve([(verts, faces, 128)])
+    assert srv.stats.report()["stages"]["starved"]["count"] == 0
+    time.sleep(0.05)
+    srv.serve([(verts, faces, 128)])
+    st = srv.stats.report()["stages"]["starved"]
+    assert st["count"] == 1 and st["total_s"] >= 0.05
+
+
+def test_no_starved_between_items_of_one_async_plan():
+    from repro.data import geometry as geo
+    srv = _stage_server(False)
+    srv.warmup()
+    verts, faces = geo.car_surface(geo.sample_params(0))
+    res = srv.serve([(verts, faces, 128)] * 3)     # one plan, three items
+    assert len(res) == 3 and all(r.error is None for r in res)
+    assert srv.stats.report()["stages"]["starved"]["count"] == 0
+
+
+def test_late_dispatch_counted_when_previous_output_ready(monkeypatch):
+    from repro.data import geometry as geo
+    srv = _stage_server(False)
+    srv.warmup()
+    sample = srv._sample
+
+    def slow_sample(req, n):        # the host outlasts the device
+        time.sleep(0.3)
+        return sample(req, n)
+
+    monkeypatch.setattr(srv, "_sample", slow_sample)
+    verts, faces = geo.car_surface(geo.sample_params(0))
+    srv.serve([(verts, faces, 128)] * 2)
+    rep = srv.stats.report()
+    assert rep["late_dispatches"] == 1
+    assert rep["stages"]["starved"]["count"] == 0
+    prom = srv.stats.metrics.prometheus_text()
+    assert "serve_late_dispatches_total 1.0" in prom
+
