@@ -234,10 +234,13 @@ def csr_candidate_lists(points, n_valid, spec: GridSpec):
     left))``. The flat cell id is contiguous along z, so a query's 3x3x3
     window is 9 contiguous id ranges (one per (dx, dy) column) — 18 binary
     searches per query. The 9 segment lengths are prefix-summed into a
-    packed row of width ``neigh_cap`` and every slot maps back to
-    (segment, offset) via a scatter + running cumsum over the row. Slots
-    past ``neigh_cap`` are dropped — identical overflow semantics to the
-    dense table's ``mode='drop'`` scatter.
+    packed row of width ``neigh_cap``. Slot ``t`` lies in the last segment
+    ``j`` whose base is at most ``t`` and reads sorted position
+    ``start[j] + t - base[j]``; the bases are nondecreasing, so 9
+    elementwise compare-selects against the (N, 9) tables map every slot
+    (no scatter, scan or per-slot gather). Slots past ``neigh_cap`` are
+    dropped — identical overflow semantics to the dense table's
+    ``mode='drop'`` scatter.
 
     Memory: O(n_points) bookkeeping + the (N, C) candidate row that every
     layout materializes; nothing scales with ``spec.n_cells``.
@@ -267,16 +270,16 @@ def csr_candidate_lists(points, n_valid, spec: GridSpec):
     base = jnp.cumsum(cnt, axis=1) - cnt                       # (N, 9) excl.
     total = base[:, -1] + cnt[:, -1]                           # (N,)
 
-    # segment of slot t = (number of j with base[j] <= t) - 1: scatter one
-    # marker per segment start and cumsum them along the packed row
-    # (zero-length segments stack their markers and are skipped)
+    # slot t of segment j sits at start[j] + t - base[j], and t belongs to
+    # the last j with base[j] <= t (base is nondecreasing in j, and a
+    # zero-length segment shares its base with the next, which overwrites
+    # it): 9 elementwise compare-selects per slot, no gather, no scan
     slots = jnp.arange(spec.neigh_cap, dtype=jnp.int32)
-    rows = jnp.broadcast_to(jnp.arange(n)[:, None], (n, 9))
-    marks = jnp.zeros((n, spec.neigh_cap + 1), jnp.int32)
-    marks = marks.at[rows, jnp.clip(base, 0, spec.neigh_cap)].add(1)
-    seg = jnp.clip(jnp.cumsum(marks[:, :spec.neigh_cap], axis=1) - 1, 0, 8)
-    pos = (jnp.take_along_axis(start, seg, axis=1) + slots[None, :]
-           - jnp.take_along_axis(base, seg, axis=1))
+    shift = start - base                                       # (N, 9)
+    pos = slots[None, :] + shift[:, :1]
+    for j in range(1, 9):
+        pos = jnp.where(slots[None, :] >= base[:, j:j + 1],
+                        slots[None, :] + shift[:, j:j + 1], pos)
     cand = order[jnp.clip(pos, 0, n - 1)]                      # (N, C)
     slot_ok = slots[None, :] < total[:, None]
     self_ids = jnp.arange(n, dtype=jnp.int32)[:, None]

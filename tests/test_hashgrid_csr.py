@@ -1,6 +1,8 @@
 """Occupied-cell CSR hash grid: hypothesis-driven exactness vs cKDTree over
 adversarial cloud families, dense-vs-CSR regression, and the O(points)
 memory property (resolutions whose dense table could never be allocated)."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -156,3 +158,100 @@ def test_calibrate_layouts():
     assert c.layout == "csr"
     # csr ignores the dense budget -> at least as fine a grid
     assert c.n_cells >= d.n_cells
+
+
+def _car_cloud(n: int, seed: int) -> np.ndarray:
+    verts, faces = geo.car_surface(geo.sample_params(seed))
+    pts, _ = sample_surface(verts, faces, n, np.random.default_rng(seed))
+    return np.asarray(pts, np.float32)
+
+
+def _border_cloud(n: int, seed: int) -> np.ndarray:
+    """Uniform box with a third of the points snapped onto its x and y
+    faces, so many queries sit in the grid's border columns."""
+    rng = np.random.default_rng(seed)
+    pts = rng.random((n, 3)).astype(np.float32)
+    face = rng.integers(0, 4, n)
+    snap = rng.random(n) < 1 / 3
+    pts[snap & (face == 0), 0] = 0.0
+    pts[snap & (face == 1), 0] = 1.0
+    pts[snap & (face == 2), 1] = 0.0
+    pts[snap & (face == 3), 1] = 1.0
+    return pts
+
+
+def _numpy_csr_rows(cc, cid, n_valid, spec):
+    """Plain packing of each query's candidate row: the stable cell-sorted
+    point order read over the 9 (dx, dy) columns in ``_XY_OFFSETS`` order,
+    z ascending within a column, cut at ``neigh_cap``, self left out."""
+    order = np.argsort(cid, kind="stable")
+    sorted_cid = cid[order]
+    rx, ry, rz = spec.resolution
+    rows = []
+    for i in range(spec.n_points):
+        packed = []
+        cx, cy, cz = cc[i]
+        for dx, dy in hashgrid._XY_OFFSETS:
+            x, y = cx + dx, cy + dy
+            if i >= n_valid or not (0 <= x < rx and 0 <= y < ry):
+                continue
+            for z in range(max(cz - 1, 0), min(cz + 1, rz - 1) + 1):
+                c = (x * ry + y) * rz + z
+                lo = np.searchsorted(sorted_cid, c, side="left")
+                hi = np.searchsorted(sorted_cid, c, side="right")
+                packed.extend(order[lo:hi].tolist())
+        rows.append([p for p in packed[:spec.neigh_cap] if p != i])
+    return rows
+
+
+@pytest.mark.parametrize("case", ["car", "padded", "overflow", "coplanar",
+                                  "border"])
+def test_csr_candidate_slot_order(case):
+    """Valid candidate ids sit in the same slots, in the same order, as a
+    plain numpy packing of the CSR row: top-k breaks distance ties by slot,
+    so set equality alone would not pin the served edges."""
+    n_points = 1536
+    n_valid = 1400 if case == "padded" else n_points
+    if case == "coplanar":
+        pts = _make_cloud("coplanar", n_points, np.random.default_rng(5))
+    elif case == "border":
+        pts = _border_cloud(n_points, 6)
+    else:
+        pts = _car_cloud(n_points, 3)
+    spec = hashgrid.calibrate_spec(pts[:n_valid], 6, n_points=n_points)
+    if case == "overflow":
+        spec = hashgrid.GridSpec(n_points=n_points, k=6,
+                                 resolution=spec.resolution, neigh_cap=32)
+        assert hashgrid.overflow_count(pts, n_valid, spec) > 0
+    buf = pts.copy()
+    buf[n_valid:] = 50.0                       # padding: far, and ignored
+    valid = jnp.arange(n_points) < n_valid
+    cc, cid = map(np.asarray, hashgrid._cells(jnp.asarray(buf), valid, spec))
+    if case == "border":
+        rx, ry, _ = spec.resolution
+        on_border = ((cc[:, 0] == 0) | (cc[:, 0] == rx - 1)
+                     | (cc[:, 1] == 0) | (cc[:, 1] == ry - 1))
+        assert on_border.sum() > n_points // 4
+    cand, cand_valid, qvalid = map(np.asarray, jax.jit(
+        hashgrid.csr_candidate_lists, static_argnums=2)(
+            jnp.asarray(buf), n_valid, spec))
+    assert cand.shape == cand_valid.shape == (n_points, spec.neigh_cap)
+    np.testing.assert_array_equal(qvalid, np.arange(n_points) < n_valid)
+    expected = _numpy_csr_rows(cc, cid, n_valid, spec)
+    for i in range(n_points):
+        assert cand[i][cand_valid[i]].tolist() == expected[i], i
+
+
+def test_csr_candidate_lists_lowering_has_no_scatter():
+    """The slot -> sorted-position map is elementwise: the lowered program
+    holds no scatter and exactly one (n_points, neigh_cap) gather (the
+    candidate ids read from the sorted order)."""
+    spec = hashgrid.GridSpec(n_points=4096, k=6, resolution=(24, 12, 10),
+                             neigh_cap=256)
+    text = jax.jit(hashgrid.csr_candidate_lists, static_argnums=2).lower(
+        jax.ShapeDtypeStruct((4096, 3), jnp.float32), jnp.int32(4000),
+        spec).as_text()
+    assert "stablehlo.scatter" not in text
+    gathers = re.findall(r'"stablehlo\.gather".*-> tensor<([0-9x]+)xi32>',
+                         text)
+    assert gathers.count("4096x256") == 1, gathers
