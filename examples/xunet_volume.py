@@ -1,9 +1,10 @@
 """X-UNet3D (paper SVI): halo-partitioned volumetric prediction.
 
 Trains a reduced 3D UNet with attention gates on the analytic volume-flow
-proxy, then runs inference BOTH on the full domain and partitioned into
-halo-extended slabs — and shows the outputs agree to float tolerance while
-each slab touches only a fraction of the domain.
+proxy, its inputs featurized from each car's sampled surface as the server
+does (``repro.graphx.volume``), then runs inference BOTH on the full domain
+and partitioned into halo-extended slabs — and shows the outputs agree to
+float tolerance while each slab touches only a fraction of the domain.
 
 Run:  PYTHONPATH=src python examples/xunet_volume.py
 """
@@ -13,25 +14,30 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import unet_halo
+from repro.core.graph_build import sample_surface
 from repro.data import geometry as geo
+from repro.graphx import volume
 from repro.models import xunet3d
 from repro.optim.adam import AdamConfig, adam_init, adam_update
 
 
-def make_batch(cfg, sample_id):
+def make_batch(cfg, sample_id, n_points=2048):
+    """Voxel features of one car from its sampled surface (the serving
+    featurizer, ``repro.graphx.volume``) and the analytic flow targets."""
     params = geo.sample_params(sample_id)
-    xs = [np.linspace(-3.5, 8.5, cfg.grid[0]),
-          np.linspace(-2.25, 2.25, cfg.grid[1]),
-          np.linspace(-0.32, 3.04, cfg.grid[2])]
-    pts = np.stack(np.meshgrid(*xs, indexing="ij"), -1).reshape(-1, 3)
-    sdf = geo.signed_distance_box(pts, params)
-    feats = np.concatenate([pts, np.sin(np.pi * pts), np.cos(np.pi * pts),
-                            np.sin(2 * np.pi * pts), sdf[:, None],
-                            np.zeros((len(pts), 3))], 1).astype(np.float32)
-    targets = geo.volume_fields(pts, params)
-    shape = (1, *cfg.grid)
-    return {"inputs": jnp.asarray(feats.reshape(*shape, cfg.in_channels)),
-            "targets": jnp.asarray(targets.reshape(*shape, cfg.out_channels))}
+    verts, faces = geo.car_surface(params)
+    pts, nrm = sample_surface(verts, faces, n_points,
+                              np.random.default_rng(sample_id))
+    spec = volume.calibrate_spec(pts, cfg, n_points)
+    sdf = volume.sdf_grid(jnp.asarray(pts), jnp.asarray(nrm), n_points, spec)
+    feats = volume.slab_features(sdf, 0, cfg.grid[0], spec)
+    centres = np.stack(np.meshgrid(*volume.voxel_axes(spec), indexing="ij"),
+                       -1).reshape(-1, 3)
+    targets = geo.volume_fields(centres.astype(np.float64), params)
+    return {"inputs": feats,
+            "targets": jnp.asarray(targets.reshape(1, *cfg.grid,
+                                                   cfg.out_channels),
+                                   jnp.float32)}
 
 
 def main():
@@ -59,7 +65,7 @@ def main():
     align = 2 ** (cfg.depth - 1)
     rf = xunet3d.receptive_field(cfg)
     halo = -(-rf // align) * align
-    part = unet_halo.apply_partitioned(apply_fn, x, cfg.n_partitions, halo,
+    part = unet_halo.apply_partitioned(apply_fn, x, 4, halo,
                                        axis=1, align=align)
     print(f"receptive field={rf} voxels -> halo={halo}; "
           f"partitioned-vs-full max diff: "

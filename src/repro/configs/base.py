@@ -131,35 +131,10 @@ class ModelConfig:
 
 
 @dataclass(frozen=True)
-class GNNConfig:
-    """MeshGraphNet / X-MeshGraphNet configuration (the paper's own model)."""
+class ServingConfig:
+    """Serving knobs of ``repro.launch.serve_gnn.GNNServer``, shared by
+    every model family it serves (``GNNConfig``, ``UNetConfig``)."""
 
-    name: str = "xmgn"
-    family: str = "gnn"
-    node_in: int = 24                  # 3 pos + 3 normal + 18 fourier (paper: 24)
-    edge_in: int = 4                   # relative pos (3) + distance (1)
-    node_out: int = 4                  # pressure + 3 wall-shear components
-    hidden: int = 512
-    n_mp_layers: int = 15              # message-passing layers == halo size
-    mlp_layers: int = 2
-    act: str = "silu"
-    norm: str = "layernorm"            # per-partition-local (no batch stats!)
-    k_neighbors: int = 6
-    levels: Tuple[int, ...] = (500_000, 1_000_000, 2_000_000)  # paper's 3-level
-    n_partitions: int = 21
-    halo: int = 15                     # == n_mp_layers
-    fourier_freqs: Tuple[float, ...] = (2.0, 4.0, 8.0)  # x pi
-    graph_source: str = "host"     # training-graph construction: "host"
-                                   # (cKDTree multi-scale build in
-                                   # data/pipeline.py) or "graphx" (the
-                                   # device-resident hash-grid pipeline
-                                   # serving uses — mesh-free, same edge
-                                   # union, no cKDTree in the build)
-    agg_impl: str = "xla"          # processor scatter-add: "xla" (plain
-                                   # segment_sum), "sorted" (device argsort
-                                   # once per graph + segment_sum with
-                                   # indices_are_sorted), "pallas" (sorted
-                                   # block packing + one-hot-MXU kernel)
     # serving: padding-bucket autoscaling (repro.launch.serve_gnn). Active
     # when bucket_policy == "auto" or the server gets bucket_sizes="auto";
     # the ladder is then derived from the observed request-size histogram
@@ -218,6 +193,38 @@ class GNNConfig:
     worker_backoff_s: float = 0.05
     worker_backoff_max_s: float = 2.0
     nonfinite_guard: bool = True
+
+
+@dataclass(frozen=True)
+class GNNConfig(ServingConfig):
+    """MeshGraphNet / X-MeshGraphNet configuration (the paper's own model)."""
+
+    name: str = "xmgn"
+    family: str = "gnn"
+    node_in: int = 24                  # 3 pos + 3 normal + 18 fourier (paper: 24)
+    edge_in: int = 4                   # relative pos (3) + distance (1)
+    node_out: int = 4                  # pressure + 3 wall-shear components
+    hidden: int = 512
+    n_mp_layers: int = 15              # message-passing layers == halo size
+    mlp_layers: int = 2
+    act: str = "silu"
+    norm: str = "layernorm"            # per-partition-local (no batch stats!)
+    k_neighbors: int = 6
+    levels: Tuple[int, ...] = (500_000, 1_000_000, 2_000_000)  # paper's 3-level
+    n_partitions: int = 21
+    halo: int = 15                     # == n_mp_layers
+    fourier_freqs: Tuple[float, ...] = (2.0, 4.0, 8.0)  # x pi
+    graph_source: str = "host"     # training-graph construction: "host"
+                                   # (cKDTree multi-scale build in
+                                   # data/pipeline.py) or "graphx" (the
+                                   # device-resident hash-grid pipeline
+                                   # serving uses — mesh-free, same edge
+                                   # union, no cKDTree in the build)
+    agg_impl: str = "xla"          # processor scatter-add: "xla" (plain
+                                   # segment_sum), "sorted" (device argsort
+                                   # once per graph + segment_sum with
+                                   # indices_are_sorted), "pallas" (sorted
+                                   # block packing + one-hot-MXU kernel)
     keep_ckpts: int = 0            # training: retain the K newest periodic
                                    # step-tagged checkpoints; restore falls
                                    # back past a corrupt one (--keep-ckpts)
@@ -254,8 +261,18 @@ class GNNConfig:
 
 
 @dataclass(frozen=True)
-class UNetConfig:
-    """X-UNet3D (paper §VI): 3D UNet with attention gates + halo partitioning."""
+class UNetConfig(ServingConfig):
+    """X-UNet3D (arXiv:2411.17164 §VI): 3D UNet with attention gates + halo
+    partitioning, served as volume fields by ``GNNServer``.
+
+    The voxel grid is ``grid`` voxels of ``voxel`` metres from ``box_lo``;
+    its input features come from the request's sampled surface cloud
+    (``repro.graphx.volume``): each voxel's nearest sample within
+    ``sdf_band`` voxels, else its nearest among the cloud's first
+    ``sdf_prefix`` samples, gives the signed distance and its gradient.
+    Served, the grid is cut into ``n_partitions`` equal x-slabs, each run
+    with ``halo`` planes of context on either side (``core.unet_halo``).
+    """
 
     name: str = "xunet3d"
     family: str = "unet"
@@ -271,15 +288,23 @@ class UNetConfig:
     halo: int = 40
     n_partitions: int = 10
     grid: Tuple[int, int, int] = (800, 304, 224)   # bbox / 1.5cm voxels
+    voxel: float = 0.015               # voxel edge, metres
+    box_lo: Tuple[float, float, float] = (-3.5, -2.28, -1.0)  # grid corner
+    sdf_band: int = 3                  # exact near-surface search radius,
+                                       # voxels
+    sdf_prefix: int = 2048             # far-field fallback: nearest of the
+                                       # cloud's first sdf_prefix samples
     dtype: str = "float32"
-    source: str = "arXiv X-MeshGraphNet (NVIDIA 2024) SVI"
+    source: str = "arXiv:2411.17164 (X-MeshGraphNet), Sec. VI: X-UNet3D"
 
     def replace(self, **kw) -> "UNetConfig":
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "UNetConfig":
         return self.replace(base_channels=8, depth=2, grid=(32, 16, 16),
-                            halo=8, n_partitions=2)
+                            halo=8, n_partitions=2, voxel=0.25,
+                            box_lo=(-3.0, -2.0, -1.0), sdf_band=2,
+                            sdf_prefix=64)
 
 
 @dataclass(frozen=True)

@@ -130,10 +130,3 @@ def volume_fields(points: np.ndarray, params: CarParams) -> np.ndarray:
     w = 0.3 * pert * points[:, 2]
     p = 0.5 * (1.0 - u ** 2 - v ** 2 - w ** 2)
     return np.stack([u, v, w, p], axis=1).astype(np.float32)
-
-
-def signed_distance_box(points: np.ndarray, params: CarParams) -> np.ndarray:
-    """Cheap SDF proxy to the car body (ellipsoidal distance)."""
-    q = points / np.array([params.length / 2, params.width / 2,
-                           params.height / 2])
-    return (np.linalg.norm(q, axis=1) - 1.0).astype(np.float32)

@@ -212,6 +212,49 @@ def make_batched_infer_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
     return jax.jit(batched)
 
 
+def make_volume_infer_fn(cfg, spec, *, donate: bool = False):
+    """The X-UNet3D bucket program: ``(params, (B, N, 3) points, (B, N, 3)
+    normals, (B,) n_valid) -> (B, out_channels, X, Y, Z)`` volume fields,
+    channel-major (a channel-minor layout of 4 would be padded to the
+    TPU's 128 lanes).
+
+    Per request: the signed distance and its gradient over the grid
+    (``graphx.volume.sdf_grid``), then a ``lax.scan`` over
+    ``cfg.n_partitions`` equal halo windows (``core.unet_halo``), each
+    featurized from the map (``graphx.volume.slab_features``) and run
+    through the U-Net; the owned planes of each window are written into
+    the output. Requests of a batch run one after another (``lax.map``):
+    a slab's activations, not the batch, set the memory. ``donate``
+    follows :func:`make_batched_infer_fn`.
+    """
+    from repro.core import unet_halo
+    from repro.graphx import volume
+    from repro.models import xunet3d
+    sw = unet_halo.slab_windows(cfg.grid[0], cfg.n_partitions, cfg.halo,
+                                2 ** (cfg.depth - 1))
+
+    def one(params, points, normals, n_valid):
+        with jax.named_scope("graphx/volume_features"):
+            sdf = volume.sdf_grid(points, normals, n_valid, spec)
+
+        def slab(x0):
+            with jax.named_scope("graphx/volume_features"):
+                x = volume.slab_features(sdf, x0, sw.window, spec)
+            return jnp.moveaxis(xunet3d.apply(params, cfg, x)[0], -1, 0)
+
+        return unet_halo.scan_slabs(slab, sw,
+                                    (cfg.out_channels,) + tuple(cfg.grid),
+                                    jnp.float32)
+
+    def batched(params, points, normals, n_valid):
+        return jax.lax.map(lambda a: one(params, *a),
+                           (points, normals, n_valid))
+
+    if donate and jax.default_backend() != "cpu":
+        return jax.jit(batched, donate_argnums=(1, 2, 3))
+    return jax.jit(batched)
+
+
 def make_prefill_fn(cfg: GNNConfig, ms: MultiscaleSpec, *,
                     knn_impl: str = "xla",
                     norm_in: Optional[Tuple[np.ndarray, np.ndarray]] = None,

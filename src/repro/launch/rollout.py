@@ -154,6 +154,9 @@ class RolloutEngine:
     def __init__(self, server, *, slots: Optional[int] = None,
                  steps_per_flush: Optional[int] = None):
         cfg = server.cfg
+        if server.is_volume:
+            raise ValueError("the unet family has no rollout mode: X-UNet3D "
+                             "answers one steady volume per geometry")
         self.server = server
         self.slots = max(int(cfg.rollout_slots if slots is None else slots), 1)
         spf = int(cfg.rollout_steps_per_flush if steps_per_flush is None

@@ -129,11 +129,12 @@ from repro.ckpt import compile_cache
 from repro.configs.base import GNNConfig
 from repro.core.graph_build import sample_surface
 from repro.data import geometry as geo
-from repro.graphx import hashgrid, sharded
+from repro.core import unet_halo
+from repro.graphx import hashgrid, sharded, volume
 from repro.graphx.multiscale import MultiscaleSpec
-from repro.graphx.pipeline import make_batched_infer_fn
+from repro.graphx.pipeline import make_batched_infer_fn, make_volume_infer_fn
 from repro.launch.sharding import mesh_for_shards, shard_put
-from repro.models import meshgraphnet
+from repro.models import meshgraphnet, xunet3d
 from repro.resilience import faults
 from repro.telemetry import (MetricsRegistry, Telemetry,
                              default_size_buckets, warn_once)
@@ -185,7 +186,8 @@ def load_gnn_checkpoint(path: str):
 class Bucket:
     """One padding bucket: static shapes + its compiled batched infer fn."""
     n_points: int
-    ms: MultiscaleSpec
+    ms: MultiscaleSpec                 # graph grids; for the unet family
+                                       # the band search's VolumeSpec
     infer: object                      # jitted batched fn (unsharded mode)
     compiles: int = 0                  # ACTUAL XLA compiles (backend built it)
     cache_loads: int = 0               # programs loaded, not compiled (disk
@@ -214,7 +216,9 @@ class Request:
 class Result:
     request_id: int
     points: np.ndarray                 # (n, 3) sampled surface points
-    fields: np.ndarray                 # (n, node_out) predicted fields
+    fields: np.ndarray                 # (n, node_out) predicted fields; for
+                                       # the unet family (X, Y, Z, out) over
+                                       # the whole voxel grid
     latency_s: float
     bucket: int
     batch_size: int
@@ -266,13 +270,17 @@ class ServerStats:
     late_dispatches: int = 0           # program calls that found the previous
                                        # execution already finished: the
                                        # device idled for an unmeasured time
+    volume_voxels_owned: int = 0       # unet family: voxels answered
+    volume_voxels_computed: int = 0    # unet family: voxels run through the
+                                       # U-Net, halo windows included
     lock: threading.Lock = field(default_factory=threading.Lock,
                                  repr=False, compare=False)
 
     _RESILIENCE = ("timed_out_requests", "rejected_overload",
                    "nonfinite_results", "worker_crashes", "worker_restarts",
                    "quarantined_buckets", "bucket_fallbacks")
-    _COUNTERS = _RESILIENCE + ("late_dispatches",)
+    _COUNTERS = _RESILIENCE + ("late_dispatches", "volume_voxels_owned",
+                               "volume_voxels_computed")
 
     def __post_init__(self):
         self._recent_lat: deque = deque(maxlen=self.recent_cap)
@@ -449,6 +457,14 @@ class GNNServer:
     overlapped with the in-flight XLA call); ``agg_impl`` overrides
     ``cfg.agg_impl`` for the processor scatter-add.
 
+    The model family follows ``cfg.family``: ``"gnn"`` (``GNNConfig``)
+    answers each sampled point with surface fields; ``"unet"``
+    (``UNetConfig``, X-UNet3D) answers the whole voxel grid around the
+    geometry with volume fields, from the same sampled cloud, through the
+    same worker (its bucket program is
+    ``graphx.pipeline.make_volume_infer_fn``). The unet family has no
+    sharded, rollout or deploy-artifact mode.
+
     ``bucket_sizes`` is either a static ladder of point counts or the
     string ``"auto"``: the autoscaler then starts with an empty ladder and
     derives bucket sizes from traffic (see the module docstring). Passing a
@@ -456,6 +472,8 @@ class GNNServer:
     autoscaler with those sizes. The auto policy applies sharded and
     unsharded alike (sharded buckets derive their ShardSpec per size).
     """
+
+    FAMILIES = ("gnn", "unet")
 
     def __init__(self, cfg: GNNConfig,
                  bucket_sizes: Union[str, Sequence[int]] = (1024,),
@@ -476,9 +494,18 @@ class GNNServer:
         # programs (restart, ladder growth, LRU evict→rebuild) hit disk
         if cfg.compile_cache_dir:
             compile_cache.enable(cfg.compile_cache_dir)
-        if agg_impl is not None:
+        if cfg.family not in self.FAMILIES:
+            raise ValueError(f"GNNServer serves the families "
+                             f"{self.FAMILIES}, not {cfg.family!r}")
+        self.is_volume = cfg.family == "unet"
+        if self.is_volume and int(shard_devices) > 1:
+            raise ValueError("the unet family has no sharded serving mode: "
+                             "its slabs run inside one program on one "
+                             "device (shard_devices must be 1)")
+        if agg_impl is not None and not self.is_volume:
             cfg = cfg.replace(agg_impl=agg_impl)
-        if cfg.agg_impl == "pallas" and int(shard_devices) == 1:
+        if (not self.is_volume and cfg.agg_impl == "pallas"
+                and int(shard_devices) == 1):
             # the batched pipeline vmaps the exactness lax.cond into a
             # select, which executes BOTH the kernel and its fallback every
             # layer; the kernel path is meant for the unbatched per-shard
@@ -508,8 +535,14 @@ class GNNServer:
                                       if shard_pad_factor is None
                                       else shard_pad_factor)
         self.async_flush = bool(async_flush)
-        self.params = params if params is not None else meshgraphnet.init(
+        init = xunet3d.init if self.is_volume else meshgraphnet.init
+        self.params = params if params is not None else init(
             jax.random.PRNGKey(seed), cfg)
+        # unet family: the bucket program's slab windows and output width
+        self._slabs = (unet_halo.slab_windows(
+            cfg.grid[0], cfg.n_partitions, cfg.halo, 2 ** (cfg.depth - 1))
+            if self.is_volume else None)
+        self._n_out = cfg.out_channels if self.is_volume else cfg.node_out
         self.seed = int(seed)
         self._knn_impl = knn_impl
         self._norm_in = norm_in
@@ -625,6 +658,12 @@ class GNNServer:
             return ms
         faults.fire("bucket.calibrate")
         cfg = self.cfg
+        if self.is_volume:
+            ms = volume.calibrate_spec(self._sample_reference(n)[0], cfg, n)
+            self._calib[n] = ms
+            with self.stats.lock:
+                self.stats.bucket_calibrations += 1
+            return ms
         levels = _level_sizes(n, self.n_levels)
         ref_pts, _ = self._sample_reference(n)
         grids = tuple(hashgrid.calibrate_spec(ref_pts[:m], cfg.k_neighbors,
@@ -708,11 +747,16 @@ class GNNServer:
             with self.stats.lock:
                 self.stats.cache_loads += 1
             return b
-        infer = make_batched_infer_fn(cfg, ms, knn_impl=self._knn_impl,
-                                      norm_in=self._norm_in,
-                                      norm_out=self._norm_out,
-                                      donate=self._donate)
-        return Bucket(n_points=n, ms=ms, infer=infer)
+        return Bucket(n_points=n, ms=ms, infer=self._make_infer(ms))
+
+    def _make_infer(self, ms):
+        """The jitted batched program of an unsharded bucket."""
+        if self.is_volume:
+            return make_volume_infer_fn(self.cfg, ms, donate=self._donate)
+        return make_batched_infer_fn(self.cfg, ms, knn_impl=self._knn_impl,
+                                     norm_in=self._norm_in,
+                                     norm_out=self._norm_out,
+                                     donate=self._donate)
 
     @classmethod
     def from_checkpoint(cls, path: str, cfg: GNNConfig,
@@ -799,10 +843,7 @@ class GNNServer:
                     knn_impl=self._knn_impl, norm_in=self._norm_in,
                     norm_out=self._norm_out, pack_width=self.max_batch)
             else:
-                infer = make_batched_infer_fn(
-                    self.cfg, b.ms, knn_impl=self._knn_impl,
-                    norm_in=self._norm_in, norm_out=self._norm_out,
-                    donate=self._donate)
+                infer = self._make_infer(b.ms)
         arg_specs = self._shard_arg_specs(n_points) if sharded_mode else \
             self._bucket_arg_specs(n_points)
         return infer.lower(*arg_specs).compile()
@@ -816,6 +857,7 @@ class GNNServer:
         serialization) — everything :meth:`from_artifact` needs to serve
         the first request with zero XLA compiles and zero recalibration.
         Returns a small summary dict (bucket sizes, aot sizes, path).
+        The unet family has no artifact (``ValueError``).
 
         Sharded servers are supported like unsharded ones: the artifact
         additionally freezes every calibrated ShardSpec (per-shard
@@ -824,6 +866,9 @@ class GNNServer:
         backend cannot serialize them the restored server falls back to
         jit + the persistent compilation cache, never recalibration.
         """
+        if self.is_volume:
+            raise ValueError("deploy artifacts hold GNN buckets only; the "
+                             "unet family has none")
         with self._cond:
             live = sorted(self._buckets)
             ladder = sorted(set(self._buckets) | self._ladder)
@@ -1173,7 +1218,7 @@ class GNNServer:
         waited = t - (req.t_submit or t)
         return Result(request_id=req.request_id,
                       points=np.zeros((0, 3), np.float32),
-                      fields=np.zeros((0, self.cfg.node_out), np.float32),
+                      fields=np.zeros((0, self._n_out), np.float32),
                       latency_s=waited, bucket=n, batch_size=0,
                       error=f"deadline exceeded: request waited "
                             f"{waited * 1e3:.1f} ms, dropped before "
@@ -1186,7 +1231,7 @@ class GNNServer:
         self._next_id += 1
         self._done[rid] = Result(
             request_id=rid, points=np.zeros((0, 3), np.float32),
-            fields=np.zeros((0, self.cfg.node_out), np.float32),
+            fields=np.zeros((0, self._n_out), np.float32),
             latency_s=0.0, bucket=bucket, batch_size=0, error=reason)
         self._cond.notify_all()
         return rid
@@ -1316,9 +1361,15 @@ class GNNServer:
     def _check_cloud(self, b: Bucket, pts: np.ndarray, rid: int) -> int:
         """Cheap numpy guard against out-of-distribution geometries: a cloud
         denser than the calibration reference can overflow a grid's
-        neighborhood capacity, which would silently drop kNN candidates."""
-        dropped = sum(hashgrid.overflow_count(pts[:m], m, g)
-                      for m, g in zip(b.ms.level_sizes, b.ms.grids))
+        neighborhood capacity, which would silently drop kNN candidates —
+        or, for the unet family, a cell of the band search's table, which
+        would give voxels a wrong nearest sample (such a request is always
+        rejected)."""
+        if self.is_volume:
+            dropped = volume.overflow_count(pts, b.ms)
+        else:
+            dropped = sum(hashgrid.overflow_count(pts[:m], m, g)
+                          for m, g in zip(b.ms.level_sizes, b.ms.grids))
         if dropped:
             with self.stats.lock:
                 self.stats.overflow_requests += 1
@@ -1337,11 +1388,17 @@ class GNNServer:
         if record:
             with self.stats.lock:
                 self.stats.rejected_requests += 1
-        nan = np.full((n_points, self.cfg.node_out), np.nan, np.float32)
         t = time.perf_counter()
-        return Result(request_id=req.request_id, points=pts, fields=nan,
+        return Result(request_id=req.request_id, points=pts,
+                      fields=self._nan_fields(n_points),
                       latency_s=t - (req.t_submit or t), bucket=n_points,
                       batch_size=0, error=reason)
+
+    def _nan_fields(self, n_points: int) -> np.ndarray:
+        """Fields of an error Result: NaN per point of the bucket; for the
+        unet family, whose answer is a whole voxel grid, none."""
+        rows = 0 if self.is_volume else n_points
+        return np.full((rows, self._n_out), np.nan, np.float32)
 
     def _nonfinite_result(self, b: Bucket, req: Request,
                           vals: np.ndarray) -> Result:
@@ -1353,10 +1410,10 @@ class GNNServer:
                f"values are NaN/Inf (bucket {b.n_points})")
         if self._warn_once(("nonfinite", b.n_points), msg):
             warnings.warn(msg)
-        nan = np.full((b.n_points, self.cfg.node_out), np.nan, np.float32)
         t = time.perf_counter()
         return Result(request_id=req.request_id,
-                      points=np.zeros((0, 3), np.float32), fields=nan,
+                      points=np.zeros((0, 3), np.float32),
+                      fields=self._nan_fields(b.n_points),
                       latency_s=t - (req.t_submit or t), bucket=b.n_points,
                       batch_size=0, error=msg)
 
@@ -1403,7 +1460,7 @@ class GNNServer:
                     with self._stage("check_cloud", True, bucket=b.n_points,
                                      rid=req.request_id):
                         dropped = self._check_cloud(b, pts, req.request_id)
-                if dropped and self.reject_overflow:
+                if dropped and (self.reject_overflow or self.is_volume):
                     results.append(self._reject(
                         req, b.n_points,
                         f"grid overflow: {dropped} candidate slots "
@@ -1618,9 +1675,13 @@ class GNNServer:
                     continue
                 lat = t_done - (req.t_submit or t_done)
                 lats.append(lat)
+                # a volume answer is channel-major on the device (see
+                # make_volume_infer_fn); the client gets (X, Y, Z, out)
+                fields = (np.moveaxis(out[i], 0, -1) if self.is_volume
+                          else out[i])
                 results.append(Result(
                     request_id=req.request_id, points=fl.pts[i],
-                    fields=out[i], latency_s=lat, bucket=b.n_points,
+                    fields=fields, latency_s=lat, bucket=b.n_points,
                     batch_size=len(ok_reqs)))
                 if self.telemetry.enabled:
                     self.telemetry.tracer.record_span(
@@ -1637,6 +1698,12 @@ class GNNServer:
                     self.stats.padding_points += \
                         sum(w for _, w in padding) + replay * b.n_points
                 b.served += len(ok_reqs)
+                if self.is_volume:
+                    sw, (_, y, z) = self._slabs, self.cfg.grid
+                    self.stats.bump("volume_voxels_owned",
+                                    len(lats) * self.cfg.grid[0] * y * z)
+                    self.stats.bump("volume_voxels_computed",
+                                    len(lats) * sw.n * sw.window * y * z)
         return results
 
     def _run_batch(self, b: Bucket, reqs: List[Request],

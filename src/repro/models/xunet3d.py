@@ -61,10 +61,17 @@ def gate_init(key, c_skip, c_gate, dtype=jnp.float32):
     }
 
 
-def gate_apply(p, skip, gate):
-    """Attention gate (1x1 convs — local): skip * sigmoid(psi(relu(wx*x+wg*g)))."""
-    q = jax.nn.relu(conv3d(p["wx"], skip) + conv3d(p["wg"], gate))
-    return skip * jax.nn.sigmoid(conv3d(p["psi"], q))
+def gate_apply(p, skip, coarse):
+    """Attention gate (1x1 convs — local):
+    skip * sigmoid(psi(relu(wx*skip + wg*g))) with ``g`` the nearest 2x
+    upsample of ``coarse``. A 1x1 conv commutes with nearest upsampling, so
+    ``wg*g`` is computed at the coarse level and upsampled; ``psi`` (one
+    output channel) is a sum over channels, which leaves no 1-channel
+    tensor for a TPU to pad to 128 lanes."""
+    q = jax.nn.relu(conv3d(p["wx"], skip)
+                    + _upsample(conv3d(p["wg"], coarse)))
+    psi = jnp.einsum("bxyzc,c->bxyz", q, p["psi"]["w"][0, 0, 0, :, 0])
+    return skip * jax.nn.sigmoid(psi + p["psi"]["b"][0])[..., None]
 
 
 def init(key, cfg: UNetConfig, dtype=jnp.float32):
@@ -105,22 +112,32 @@ def _upsample(x):
 
 def apply(params, cfg: UNetConfig, x):
     """x: (B, X, Y, Z, in_channels) -> (B, X, Y, Z, out_channels).
-    Spatial dims must be divisible by 2**(depth-1)."""
+    Spatial dims must be divisible by 2**(depth-1). Named scopes
+    ``unet/encode``, ``unet/gate``, ``unet/decode`` and ``unet/head`` label
+    the compiled ops by stage."""
     act = cfg.act
     skips = []
-    for i, bp in enumerate(params["enc"]):
-        x = block_apply(bp, x, act)
-        if i < cfg.depth - 1:
-            skips.append(x)
-            x = _pool(x)
+    with jax.named_scope("unet/encode"):
+        for i, bp in enumerate(params["enc"]):
+            x = block_apply(bp, x, act)
+            if i < cfg.depth - 1:
+                skips.append(x)
+                x = _pool(x)
     for j, (up, gp, bp) in enumerate(zip(params["ups"], params["gates"],
                                          params["dec"])):
-        x = conv3d(up, _upsample(x))
+        # nearest upsample then 1x1 conv, computed in the other (equal)
+        # order: the conv runs on the coarse level's voxels
+        with jax.named_scope("unet/decode"):
+            u = conv3d(up, x)
         skip = skips[-(j + 1)]
         if gp is not None:
-            skip = gate_apply(gp, skip, x)
-        x = block_apply(bp, jnp.concatenate([skip, x], axis=-1), act)
-    return conv3d(params["head"], x)
+            with jax.named_scope("unet/gate"):
+                skip = gate_apply(gp, skip, u)
+        with jax.named_scope("unet/decode"):
+            x = block_apply(bp, jnp.concatenate([skip, _upsample(u)], -1),
+                            act)
+    with jax.named_scope("unet/head"):
+        return conv3d(params["head"], x)
 
 
 def receptive_field(cfg: UNetConfig) -> int:
